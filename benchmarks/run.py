@@ -765,6 +765,9 @@ def bench_serve_tp_scaling():
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # The child's devices are fake CPU ones: pin its platform so it never
+    # reaches for an accelerator this process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     body = textwrap.dedent("""
@@ -1005,6 +1008,8 @@ def main(argv=None) -> None:
             doc = (BENCHES[name].__doc__ or "").strip().splitlines()
             print(f"{name}: {doc[0] if doc else ''}")
         return
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     names = args.only or list(BENCHES)
     print("name,us_per_call,derived")
     for name in names:

@@ -52,7 +52,11 @@ def compute_scale(x, cfg: QuantConfig):
         amax = jnp.max(jnp.abs(x), axis=axes, keepdims=True)
     else:
         amax = jnp.max(jnp.abs(x))
-    return jnp.maximum(amax, cfg.eps) / cfg.qmax
+    # Reciprocal-multiply, as in kernels/ref.quant_scale: XLA rewrites a
+    # division by the constant qmax this way under jit but not eagerly, so
+    # writing it out keeps jitted and eager preparation bit-identical.
+    return jnp.maximum(amax, cfg.eps) * (jnp.float32(1.0)
+                                         / jnp.float32(cfg.qmax))
 
 
 def quantize(x, cfg: QuantConfig, scale=None):

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
 
@@ -85,8 +84,8 @@ def run_pipeline(mesh: Mesh, stage_fn: StageFn, stage_params: Any,
         summed: jax.Array = jax.lax.psum(out * mask, axis_name)
         return summed
 
-    f = shard_map(shmapped, mesh=mesh,
-                  in_specs=(P(axis_name), P()), out_specs=P(),
-                  check_vma=False)
+    f = jax.shard_map(shmapped, mesh=mesh,
+                      in_specs=(P(axis_name), P()), out_specs=P(),
+                      check_vma=False)
     y: jax.Array = f(stage_params, x_micro)
     return y

@@ -14,33 +14,10 @@ on CPU tests.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Sequence, Union
+from typing import Any, Optional, Sequence, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# shard_map moved from jax.experimental to the jax top level across JAX
-# releases, and its replication-check kwarg was renamed check_rep ->
-# check_vma in the move.  Resolve both once here so every shard_map user
-# (tp_matmul, pipeline, tests) works on both sides of the move; callers
-# use the new-style ``check_vma`` spelling.
-_shard_map: Callable[..., Any]
-try:
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # newer jax removed the experimental alias
-    _shard_map = jax.shard_map
-
-
-def shard_map(f: Callable[..., Any], *args: Any,
-              check_vma: Optional[bool] = None,
-              **kwargs: Any) -> Callable[..., Any]:
-    import inspect
-    if check_vma is not None:
-        params = inspect.signature(_shard_map).parameters
-        kwargs["check_vma" if "check_vma" in params else "check_rep"] = \
-            check_vma
-    wrapped: Callable[..., Any] = _shard_map(f, *args, **kwargs)
-    return wrapped
 
 Axis = Union[str, Sequence[str], None]
 # A logical axis resolved against a concrete mesh.

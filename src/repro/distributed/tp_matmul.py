@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 from repro.kernels import ops
 
 
@@ -103,7 +102,7 @@ def tp_mlp_block(mesh: Mesh, x: jax.Array, w_up: jax.Array,
         return y
 
     lead = tuple([None] * (x.ndim - 1))
-    fm = shard_map(
+    fm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(*lead, axis_name),       # x: SP on last dim
                   P(None, axis_name),        # w_up: N-sharded

@@ -193,9 +193,7 @@ def gathered_matmul(x: jax.Array, qw: Any, prec: Any, *, tp: TPConfig,
     q, s = _act_quant_pmax(x.astype(jnp.float32), prec.a_bits, tp.axis)
     q_all = gather_codes(q, prec.a_bits, tp.axis, signed=prec.a_signed)
     y_loc = ops.dequant_matmul(q_all, s, qw, prec, out_dtype)
-    y: jax.Array = jax.lax.all_gather(y_loc, tp.axis, axis=y_loc.ndim - 1,
-                                      tiled=True)
-    return y
+    return _output_gather(y_loc, tp)
 
 
 def gathered_grouped_matmul(x: jax.Array, qw: Any, row_groups: Any,
@@ -228,8 +226,17 @@ def gathered_grouped_matmul(x: jax.Array, qw: Any, row_groups: Any,
     y_loc = ops.fused_decode_linear(x, qw, row_groups, perm,
                                     pre_quant=(q_all, s),
                                     out_dtype=x.dtype)
-    y: jax.Array = jax.lax.all_gather(y_loc, tp.axis, axis=y_loc.ndim - 1,
-                                      tiled=True)
+    return _output_gather(y_loc, tp)
+
+
+def _output_gather(y_loc: jax.Array, tp: TPConfig) -> jax.Array:
+    """Gather the output columns back to the replicated [..., N_full] in
+    the residual dtype — the only float collective of the wire.  The
+    ``tp_output_gather`` scope names it in the compiled HLO's op_name, so
+    an audit can tell it from the quantized code gathers."""
+    with jax.named_scope("tp_output_gather"):
+        y: jax.Array = jax.lax.all_gather(y_loc, tp.axis,
+                                          axis=y_loc.ndim - 1, tiled=True)
     return y
 
 

@@ -14,6 +14,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# Bytes of the f32 input block one grid step holds in VMEM.  The block keeps
+# whole rows (the amax reduction runs over K), so its row count shrinks as K
+# grows: at d_ff 12800 a 128-row block (and its temporaries) overflows the
+# TPU's scoped VMEM, while 2 MiB leaves room for double buffering.
+BLOCK_BYTES: int = 2 << 20
+
+
+def block_rows(m: int, k: int) -> int:
+    """Rows per grid step for an [m, k] f32 input: every row whole, at most
+    128 rows, at most :data:`BLOCK_BYTES` of input.  Callers pad ``m`` to a
+    multiple of it.  Rows quantize independently, so the choice never
+    changes a bit of the result."""
+    cap = max(8, min(128, BLOCK_BYTES // (4 * k) // 8 * 8))
+    return m if m <= cap else cap
+
 
 def _kernel(x_ref: Any, q_ref: Any, s_ref: Any, *, qmin: int,
             qmax: int) -> None:
@@ -36,7 +51,8 @@ def act_quant(x: jax.Array, *, bits: int = 8, signed: bool = True,
               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """Per-row symmetric quantization. x: f32 [M, K] -> (int8 [M, K], f32 [M, 1]).
 
-    M must tile by bm (ops.py pads); K is kept whole in VMEM (row reduction)."""
+    M must tile by bm (ops.py pads to :func:`block_rows`); K is kept whole
+    in VMEM (row reduction)."""
     m, k = x.shape
     assert m % bm == 0, (m, bm)
     qmax = (1 << (bits - 1)) - 1 if signed else (1 << bits) - 1

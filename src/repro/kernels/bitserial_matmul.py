@@ -115,17 +115,17 @@ def _packed_kernel(x_ref: Any, w_ref: Any, o_ref: Any, acc_ref: Any, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
-    packed = w_ref[...]  # uint8 [bk, bn], 2-bit fields, plane c at bits 2c
+    # uint8 [bk, bn], 2-bit fields, plane c at bits 2c; widened to int32
+    # because Mosaic cannot lower a shift of a uint8 vector.
+    packed = w_ref[...].astype(jnp.int32)
     acc = acc_ref[...]
     nplanes = len(shifts)
     for c, s in enumerate(shifts):
-        field = (packed >> (base + 2 * c)) & 0x3  # uint8 in [0, 3]
+        field = (packed >> (base + 2 * c)) & 0x3  # in [0, 3]
         if signed and c == nplanes - 1:
             # MSB plane: reinterpret 2-bit field as signed [-2, 1].
-            plane = jnp.where(field >= 2, field.astype(jnp.int8) - 4,
-                              field.astype(jnp.int8))
-        else:
-            plane = field.astype(jnp.int8)
+            field = jnp.where(field >= 2, field - 4, field)
+        plane = field.astype(jnp.int8)
         part = jax.lax.dot_general(
             x, plane,
             dimension_numbers=(((1,), (0,)), ((), ())),

@@ -60,11 +60,11 @@ def _plane(w_ref: Any, c: int, *, packed: bool, store_planes: int,
     if not packed:
         return w_ref[c]
     field_idx = store_planes - 1 - c        # MSB-first plane c <-> byte field
-    field = (w_ref[...] >> (2 * field_idx)) & 0x3
+    # Widen before shifting: Mosaic cannot lower a shift of a uint8 vector.
+    field = (w_ref[...].astype(jnp.int32) >> (2 * field_idx)) & 0x3
     if signed and field_idx == store_planes - 1:
         # The store's top field is the sign-carrying MSB chunk.
-        return jnp.where(field >= 2, field.astype(jnp.int8) - 4,
-                         field.astype(jnp.int8))
+        field = jnp.where(field >= 2, field - 4, field)
     return field.astype(jnp.int8)
 
 

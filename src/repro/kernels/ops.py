@@ -6,7 +6,7 @@ routes through one of four backends (see core.policy.BACKENDS):
   dense       bf16/f32 matmul (fp baseline)
   fake_quant  QAT fake-quantized operands, dense matmul (training path)
   decomposed  integer plane-decomposed matmul in plain HLO (serving, dry-run)
-  pallas      the Pallas TPU kernels (interpret=True off-TPU)
+  pallas      the Pallas TPU kernels (interpreted on CPU)
 
 Weights for the integer paths are prepared once into a ``QuantizedWeight``
 (planes + per-channel scale) — the analogue of preloading decomposed weights
@@ -45,6 +45,19 @@ ActQuants = Dict[Any, Tuple[jax.Array, jax.Array]]
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    """Pallas mode for the default backend: compiled on TPU, interpreted on
+    CPU (tests, tiny runs).  Any other backend raises — the kernels never
+    fall back to interpret mode where a device is attached."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted "
+                       f"on CPU; the {backend!r} backend has neither")
 
 
 @dataclasses.dataclass
@@ -234,11 +247,11 @@ def act_quant_pallas(
         x: jax.Array, *, a_bits: int = 8, signed: bool = True,
         interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
     """Direct Pallas activation-quant call (padded), for the serving hot path."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret() if interpret is None else interpret
     lead, k = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
-    bm = min(128, m) if m % 128 != 0 else 128
+    bm = act_quant_kernel.block_rows(m, k)
     x2p = _pad_to(x2, bm, 0)
     q, s = act_quant_kernel.act_quant(x2p, bits=a_bits, signed=signed, bm=bm,
                                       interpret=interpret)
@@ -293,7 +306,7 @@ def bitserial_matmul_pallas(x_int8: jax.Array, qw: QuantizedWeight, *,
         if sum(r for r, _ in row_groups) != x_int8.shape[0]:
             raise ValueError(f"row_groups {row_groups} do not cover leading "
                              f"axis {x_int8.shape[0]}")
-        interpret = (not _on_tpu()) if interpret is None else interpret
+        interpret = _interpret() if interpret is None else interpret
         k, n = qw.kn
         lead = x_int8.shape[:-1]
         x2 = x_int8.reshape(-1, k)
@@ -316,7 +329,7 @@ def bitserial_matmul_pallas(x_int8: jax.Array, qw: QuantizedWeight, *,
             store_planes=decompose.num_planes(qw.w_bits, qw.signed),
             signed=qw.signed, bm=bm_eff, bn=bn, bk=bk, interpret=interpret)
         return out[:m, :n].reshape(*lead, n)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret() if interpret is None else interpret
     eff = qw.w_bits if eff_bits is None else eff_bits
     if eff != qw.w_bits and not qw.msb_first:
         raise ValueError(
@@ -373,12 +386,12 @@ def _quantize_activations_rows(
     x2 = x.astype(jnp.float32).reshape(-1, k)
     if use_pallas:
         m = x2.shape[0]
-        bm = min(128, m) if m % 128 != 0 else 128
+        bm = act_quant_kernel.block_rows(m, k)
         x2p = _pad_to(x2, bm, 0)
         # Real qmax is always >= 1, so this only lifts zero padding rows.
         qmaxp = jnp.maximum(_pad_to(qmax_full, bm, 0), 1.0)
         q, s = act_quant_kernel.act_quant_rows(x2p, qmaxp, bm=bm,
-                                               interpret=not _on_tpu())
+                                               interpret=_interpret())
         q, s = q[:m], s[:m]
     else:
         q, s = ref.act_quant_rows_ref(x2, qmax_full)
@@ -493,7 +506,7 @@ def fused_decode_linear(x: jax.Array, qw: QuantizedWeight,
             x2, qw.get_planes_msb()[:pmax], mult)
         out = (acc.astype(jnp.float32) * s2 * ws).astype(out_dtype)
         return out.reshape(*lead, n)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret() if interpret is None else interpret
     m = x2.shape[0]
     bm_eff = min(bm, max(8, m))
     x2p = _pad_to(_pad_to(x2, bm_eff, 0), bk, 1)

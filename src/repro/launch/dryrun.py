@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"      # placeholder devices are CPU ones
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count="
     + os.environ.get("REPRO_DRYRUN_DEVICES", "512")
@@ -8,9 +9,9 @@ os.environ["XLA_FLAGS"] = (
 production mesh with placeholder host devices; record memory/cost/collective
 analysis for the roofline (EXPERIMENTS.md §Dry-run / §Roofline).
 
-The XLA_FLAGS line above MUST stay the first statement — jax locks the device
-count on first init.  It is process-local: smoke tests and benches never
-import this module.
+The environment lines above MUST stay the first statements — jax locks the
+platform and device count on first init.  They are process-local: smoke
+tests and benches never import this module.
 """
 import argparse
 import dataclasses
@@ -28,7 +29,7 @@ from repro.core.policy import uniform_policy
 from repro.launch import hlo_cost
 from repro.distributed import sharding_rules as rules
 from repro.launch import specs as specs_mod
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.layers import Runtime
 from repro.models.transformer import LM
 from repro.serve.engine import prepare_params
@@ -141,7 +142,7 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool,
         return None, reason
     model = LM(cfg)
     mesh = make_production_mesh(multi_pod=multi_pod) if not reduced else \
-        jax.make_mesh((2, 2), ("data", "model"))
+        make_mesh((2, 2), ("data", "model"))
 
     with mesh:
         if shape.kind == "train":

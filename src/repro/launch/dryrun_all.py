@@ -62,7 +62,8 @@ def main():
         print(f"[run] {stem} ...", flush=True)
         try:
             r = subprocess.run(cmd, capture_output=True, text=True,
-                               timeout=args.timeout)
+                               timeout=args.timeout,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
         except subprocess.TimeoutExpired:
             print(f"[TIMEOUT] {stem}")
             failures.append((stem, "timeout"))

@@ -7,17 +7,23 @@ Multi-pod: 2x16x16 = 512 chips (pod, data, model) — DP across pods.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests / PP experiments (e.g. (4,), ('stage',))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh for tests / PP experiments (e.g. (4,), ('stage',)).
+
+    Axes are ``Auto``: the model annotates activations with
+    ``with_sharding_constraint`` (distributed/sharding.shard), which an
+    ``Explicit`` axis — ``jax.make_mesh``'s default — refuses."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serve_mesh(n: int):

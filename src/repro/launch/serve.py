@@ -60,18 +60,56 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Any, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, reduced_config
-from repro.core.policy import uniform_policy, uniform_schedule
+from repro.core.policy import (PrecisionPolicy, PrecisionSchedule,
+                               uniform_policy, uniform_schedule)
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.layers import Runtime
 from repro.models.transformer import LM
 from repro.serve import (BatchServeEngine, Request, ServeEngine, SLOPolicy,
                          prepare_params)
+from repro.serve.engine import params_prepared
 from repro.serve.handle import RequestStatus
 from repro.telemetry import Telemetry, serve_report, write_json
+
+
+def build_engine(model: LM, params: Any, *, policy: PrecisionPolicy,
+                 schedule: Optional[PrecisionSchedule] = None,
+                 packed: bool = False, baseline: bool = False,
+                 moe_dropless: bool = False, max_batch: int,
+                 max_len: int, kv_bits: Optional[int] = None,
+                 telemetry: Optional[Telemetry] = None,
+                 **serve_kwargs: Any) -> Any:
+    """The serving stack's construction path, shared by this CLI and
+    ``chip_smoke.py``: weight preload, runtime, engine.
+
+    Float ``params`` are prepared once here (the 8-bit superplane store
+    when a ``schedule`` is given); already-prepared params are served as
+    they are, so two engines can share one store.  ``baseline`` builds the
+    batch-at-a-time reference engine; ``serve_kwargs`` go to
+    ``ServeEngine``."""
+    if policy.default.backend != "dense" and not params_prepared(params):
+        t0 = time.time()
+        params, qpaths = prepare_params(
+            params, schedule.prepare_policy() if schedule else policy,
+            model, packed=packed, superplane=schedule is not None)
+        kind = "superplane" if schedule else f"w{policy.default.w_bits}"
+        print(f"prepared {len(qpaths)} weights ({kind}, packed={packed}) "
+              f"in {time.time()-t0:.1f}s")
+    rt = Runtime(policy=policy, mode="serve", moe_dropless=moe_dropless,
+                 schedule=schedule)
+    if baseline:
+        return BatchServeEngine(model, params, rt, max_batch=max_batch,
+                                max_len=max_len, kv_bits=kv_bits,
+                                telemetry=telemetry)
+    return ServeEngine(model, params, rt, max_batch=max_batch,
+                       max_len=max_len, kv_bits=kv_bits,
+                       telemetry=telemetry, **serve_kwargs)
 
 
 def main(argv=None):
@@ -300,35 +338,17 @@ def main(argv=None):
         except ValueError as e:
             ap.error(str(e))
 
+    use_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     model = LM(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
-    if args.backend != "dense":
-        # Weight preload: planes prepared ONCE, before any request arrives.
-        # With --tiers this is the 8-bit superplane store serving them all.
-        t0 = time.time()
-        params, qpaths = prepare_params(params,
-                                        schedule.prepare_policy()
-                                        if schedule else policy,
-                                        model, packed=args.packed,
-                                        superplane=schedule is not None)
-        kind = "superplane" if schedule else f"w{args.w_bits}"
-        print(f"prepared {len(qpaths)} weights "
-              f"({kind}, packed={args.packed}) "
-              f"in {time.time()-t0:.1f}s")
-    rt = Runtime(policy=policy, mode="serve", moe_dropless=args.reduced,
-                 schedule=schedule)
     # The driver always runs with telemetry attached (the zero-cost-when-
     # off contract matters for the library; a demo CLI can afford the
     # hooks) — the end-of-run report, --metrics and --trace-out all read
     # from it.
     tele = Telemetry(profile=args.profile)
-    if args.baseline:
-        engine = BatchServeEngine(model, params, rt,
-                                  max_batch=args.max_batch,
-                                  max_len=args.max_len, kv_bits=args.kv_bits,
-                                  telemetry=tele)
-    else:
+    scheduler_policy = None
+    if args.slo:
         # Rules-aware tier pricing: searched schedules (per-layer rule
         # tiers over a common default) only price differently when each
         # tier's per-layer widths are MAC-weighted.
@@ -339,21 +359,22 @@ def main(argv=None):
             # Chunk granularity: a queued request can wait up to ~2 chunks
             # before the displacement check sees it again.
             preempt_slack=2.0 * args.decode_chunk,
-            shed=args.shed) \
-            if args.slo else None
-        engine = ServeEngine(model, params, rt, max_batch=args.max_batch,
-                             max_len=args.max_len, kv_bits=args.kv_bits,
-                             decode_chunk=args.decode_chunk,
-                             mixed_tiers=not args.serialize_tiers,
-                             scheduler_policy=scheduler_policy,
-                             mesh=mesh, spill_dir=args.spill_dir,
-                             telemetry=tele)
-        if mesh is not None:
-            tp = engine._tp
-            assert tp is not None
-            print(f"mesh: {tp.n}-way tensor parallel "
-                  f"(kv_shards={tp.kv_shards}) over "
-                  f"{[d.platform for d in mesh.devices.flat]}")
+            shed=args.shed)
+    engine = build_engine(model, params, policy=policy, schedule=schedule,
+                          packed=args.packed, baseline=args.baseline,
+                          moe_dropless=args.reduced,
+                          max_batch=args.max_batch, max_len=args.max_len,
+                          kv_bits=args.kv_bits, telemetry=tele,
+                          decode_chunk=args.decode_chunk,
+                          mixed_tiers=not args.serialize_tiers,
+                          scheduler_policy=scheduler_policy, mesh=mesh,
+                          spill_dir=args.spill_dir)
+    if mesh is not None:
+        tp = engine._tp
+        assert tp is not None
+        print(f"mesh: {tp.n}-way tensor parallel "
+              f"(kv_shards={tp.kv_shards}) over "
+              f"{[d.platform for d in mesh.devices.flat]}")
 
     rng = np.random.default_rng(args.seed)
     tier_of = (lambda i: args.tiers[i % len(args.tiers)]) if args.tiers \

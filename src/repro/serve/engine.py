@@ -74,6 +74,7 @@ are priced in these ticks, keeping SLO admission fully deterministic.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import (Any, Dict, List, Mapping, Optional, Protocol, Sequence,
                     Set, Tuple, runtime_checkable)
 
@@ -86,7 +87,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import checkpoint as checkpoint_lib
 from repro.core.policy import PrecisionPolicy
 from repro.distributed import sharding_rules, tp_serve
-from repro.distributed.sharding import shard_map
 from repro.kernels import ops
 from repro.models.layers import Runtime
 from repro.models.transformer import LM
@@ -112,6 +112,14 @@ GroupLayout = Tuple[Tuple[str, int], ...]
 # in tests and the serve_precision_tiers / serve_mixed_tiers benchmarks.
 PREPARE_CALLS = 0
 
+# Serving programs compile with the bf16 roundings they state.  By default
+# XLA may keep a bf16 intermediate in f32 across a fusion ("excess
+# precision"), and which ones it keeps depends on how the program fuses.
+# Pallas kernels are fusion barriers, so the pallas and decomposed backends
+# would round in different places, and on a TPU v5e their tokens diverged.
+COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+serve_jit = functools.partial(jax.jit, compiler_options=COMPILER_OPTIONS)
+
 
 def prepare_params(params: Any, policy: PrecisionPolicy, model: LM,
                    packed: bool = False,
@@ -126,12 +134,6 @@ def prepare_params(params: Any, policy: PrecisionPolicy, model: LM,
     global PREPARE_CALLS
     PREPARE_CALLS += 1
 
-    def prep(leaf: Any, prec: Any) -> Any:
-        if superplane:
-            return ops.prepare_superplane(leaf, signed=prec.w_signed,
-                                          packed=packed)
-        return ops.prepare_weight(leaf, prec, packed=packed)
-
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     out = []
     quantized_paths: List[str] = []
@@ -141,25 +143,36 @@ def prepare_params(params: Any, policy: PrecisionPolicy, model: LM,
             and "embed" not in path and "router" not in path \
             and "conv" not in path
         if is_proj:
-            name = _path_to_layer_name(path)
-            prec = policy.lookup(name)
-            if leaf.ndim == 2:
-                qw = prep(leaf.astype(jnp.float32), prec)
-                out.append(qw)
-                quantized_paths.append(path)
-                continue
-            # Stacked (periods / experts) weights: vmap preparation over
-            # leading dims.
-            lead = leaf.shape[:-2]
-            w2 = leaf.reshape((-1,) + leaf.shape[-2:]).astype(jnp.float32)
-            qws = jax.vmap(lambda w: prep(w, prec))(w2)
-            qws = jax.tree.map(
-                lambda a: a.reshape(lead + a.shape[1:]), qws)
-            out.append(qws)
+            prec = policy.lookup(_path_to_layer_name(path))
+            out.append(_prepare_leaf(leaf, prec, packed=packed,
+                                     superplane=superplane))
             quantized_paths.append(path)
             continue
         out.append(leaf)
     return jax.tree_util.tree_unflatten(treedef, out), quantized_paths
+
+
+@functools.partial(jax.jit, static_argnames=("prec", "packed", "superplane"))
+def _prepare_leaf(leaf: jax.Array, prec: Any, *, packed: bool,
+                  superplane: bool) -> ops.QuantizedWeight:
+    """Prepare one projection weight ([..., K, N]; leading period / expert
+    dims are mapped one slice at a time).  One jitted program per weight
+    shape: run op by op, the quantize + decompose chain would hold several
+    int32 copies of a full-width weight on the device at once."""
+    def prep(w: jax.Array) -> ops.QuantizedWeight:
+        w = w.astype(jnp.float32)
+        if superplane:
+            return ops.prepare_superplane(w, signed=prec.w_signed,
+                                          packed=packed)
+        return ops.prepare_weight(w, prec, packed=packed)
+
+    if leaf.ndim == 2:
+        return prep(leaf)
+    lead = leaf.shape[:-2]
+    qws = jax.lax.map(prep, leaf.reshape((-1,) + leaf.shape[-2:]))
+    out: ops.QuantizedWeight = jax.tree.map(
+        lambda a: a.reshape(lead + a.shape[1:]), qws)
+    return out
 
 
 def _path_to_layer_name(path: str) -> str:
@@ -191,7 +204,8 @@ def _validate_request(request: Request, max_len: int,
                          "(results are keyed by uid)")
 
 
-def _params_prepared(params: Any) -> bool:
+def params_prepared(params: Any) -> bool:
+    """True once ``params`` holds prepared (QuantizedWeight) projections."""
     return any(isinstance(l, ops.QuantizedWeight) for l in jax.tree.leaves(
         params, is_leaf=lambda x: isinstance(x, ops.QuantizedWeight)))
 
@@ -203,12 +217,12 @@ def _ensure_prepared(params: Any, rt: Runtime, model: LM,
     of QuantizedWeight leaves).  A Runtime carrying a PrecisionSchedule gets
     the superplane store (one 8-bit preload serving every tier)."""
     if rt.schedule is not None:
-        if not _params_prepared(params):
+        if not params_prepared(params):
             return prepare_params(params, rt.schedule.prepare_policy(), model,
                                   packed=packed, superplane=True)
     else:
         backend = rt.policy.default.backend
-        if backend in ("decomposed", "pallas") and not _params_prepared(params):
+        if backend in ("decomposed", "pallas") and not params_prepared(params):
             return prepare_params(params, rt.policy, model, packed=packed)
     paths = [jax.tree_util.keystr(kp) for kp, l in
              jax.tree_util.tree_flatten_with_path(
@@ -719,24 +733,24 @@ class ServeEngine(_DeferredErrors):
         self._decode_chunk_fn = decode_chunk_fn
         # Speculative rounds run unsharded only (submit rejects spec on a
         # mesh engine with a clean error).
-        self._spec_round = jax.jit(
+        self._spec_round = serve_jit(
             spec_round_fn,
             static_argnames=("k", "draft_groups", "verify_groups"))
         if self.mesh is None:
-            self._prefill_slot = jax.jit(prefill_slot,
-                                         static_argnames=("tier",))
-            self._decode_chunk = jax.jit(decode_chunk_fn,
-                                         static_argnames=("n_steps", "tier",
-                                                          "groups"))
+            self._prefill_slot = serve_jit(prefill_slot,
+                                           static_argnames=("tier",))
+            self._decode_chunk = serve_jit(decode_chunk_fn,
+                                           static_argnames=("n_steps", "tier",
+                                                            "groups"))
             # Mid-stream KV migration: one jitted requantize serves every
             # (slot, from-tier, to-tier) combination — slot and code are
             # traced.
-            self._migrate_kv = jax.jit(slots_lib.migrate_kv_tier)
+            self._migrate_kv = serve_jit(slots_lib.migrate_kv_tier)
             # Preemption primitives: cut one slot out of the arena as a
             # batch-1 cache / write a snapshot back into ANY slot — both
             # with the slot index traced (one trace serves every slot).
-            self._snapshot_slot = jax.jit(slots_lib.slot_view)
-            self._restore_slot = jax.jit(slots_lib.slot_write)
+            self._snapshot_slot = serve_jit(slots_lib.slot_view)
+            self._restore_slot = serve_jit(slots_lib.slot_write)
         else:
             (self._prefill_slot, self._decode_chunk, self._migrate_kv,
              self._snapshot_slot, self._restore_slot) = self._mesh_wrap(
@@ -825,7 +839,7 @@ class ServeEngine(_DeferredErrors):
                     tp=tp)
                 return tok, tuple(jax.tree.leaves(out_c))
 
-            tok, fc2 = shard_map(
+            tok, fc2 = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(p_specs, c_specs, rep, rep, rep, rep, rep, rep,
                           rep),
@@ -851,7 +865,7 @@ class ServeEngine(_DeferredErrors):
                     return (tuple(jax.tree.leaves(out_c)), tok2, rem2,
                             toks, act)
 
-                fc2, tok2, rem2, toks, act = shard_map(
+                fc2, tok2, rem2, toks, act = jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(p_specs, c_specs, rep, rep, rep),
                     out_specs=(c_specs, rep, rep, rep, rep),
@@ -871,7 +885,7 @@ class ServeEngine(_DeferredErrors):
                 return (tuple(jax.tree.leaves(out_c)), tok2, rem2, draws,
                         toks, act)
 
-            fc2, tok2, rem2, draws, toks, act = shard_map(
+            fc2, tok2, rem2, draws, toks, act = jax.shard_map(
                 sbody, mesh=mesh,
                 in_specs=(p_specs, c_specs, rep, rep, rep, rep),
                 out_specs=(c_specs, rep, rep, rep, rep, rep),
@@ -886,9 +900,10 @@ class ServeEngine(_DeferredErrors):
                                                 code)
                 return tuple(jax.tree.leaves(out))
 
-            fc2 = shard_map(body, mesh=mesh, in_specs=(c_specs, rep, rep),
-                            out_specs=c_specs, check_vma=False)(
-                                fc, slot, code)
+            fc2 = jax.shard_map(body, mesh=mesh,
+                                in_specs=(c_specs, rep, rep),
+                                out_specs=c_specs, check_vma=False)(
+                                    fc, slot, code)
             return unflatten(c_def, fc2)
 
         # Preemption twins: slot_view/slot_write slice the SLOT axis, which
@@ -903,8 +918,8 @@ class ServeEngine(_DeferredErrors):
                 sub = slots_lib.slot_view(unflatten(c_def, fc), slot)
                 return tuple(jax.tree.leaves(sub))
 
-            fs = shard_map(body, mesh=mesh, in_specs=(c_specs, rep),
-                           out_specs=c_specs, check_vma=False)(fc, slot)
+            fs = jax.shard_map(body, mesh=mesh, in_specs=(c_specs, rep),
+                               out_specs=c_specs, check_vma=False)(fc, slot)
             return unflatten(c_def, fs)
 
         def sharded_restore(caches: Any, sub: Any, slot: Any) -> Any:
@@ -916,17 +931,18 @@ class ServeEngine(_DeferredErrors):
                                            unflatten(c_def, fs), slot)
                 return tuple(jax.tree.leaves(out))
 
-            fc2 = shard_map(body, mesh=mesh,
-                            in_specs=(c_specs, c_specs, rep),
-                            out_specs=c_specs, check_vma=False)(fc, fs, slot)
+            fc2 = jax.shard_map(body, mesh=mesh,
+                                in_specs=(c_specs, c_specs, rep),
+                                out_specs=c_specs,
+                                check_vma=False)(fc, fs, slot)
             return unflatten(c_def, fc2)
 
-        return (jax.jit(sharded_prefill, static_argnames=("tier",)),
-                jax.jit(sharded_decode,
-                        static_argnames=("n_steps", "tier", "groups")),
-                jax.jit(sharded_migrate),
-                jax.jit(sharded_snapshot),
-                jax.jit(sharded_restore))
+        return (serve_jit(sharded_prefill, static_argnames=("tier",)),
+                serve_jit(sharded_decode,
+                          static_argnames=("n_steps", "tier", "groups")),
+                serve_jit(sharded_migrate),
+                serve_jit(sharded_snapshot),
+                serve_jit(sharded_restore))
 
     # ----------------------------------------------------- dispatch counting
     def decode_dispatch_count(self, *, groups: Optional[GroupLayout] = None,
@@ -1892,10 +1908,10 @@ class BatchServeEngine(_DeferredErrors):
         self._queue: List[Request] = []
         self._seen_uids: Set[int] = set()
         self._active: Optional[_BatchState] = None
-        self._prefill = jax.jit(
+        self._prefill = serve_jit(
             lambda p, c, t, ln: model.prefill(p, rt, c, tokens=t,
                                               seq_lengths=ln))
-        self._decode = jax.jit(
+        self._decode = serve_jit(
             lambda p, c, t: model.decode_step(p, rt, c, tokens=t))
 
     # ------------------------------------------------------------------ clock

@@ -72,12 +72,12 @@ def test_compressed_psum_matches_mean():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
-        from repro.distributed.sharding import shard_map
         mesh = jax.make_mesh((8,), ("dp",))
         def f(g, e):
             return compressed_psum(g, e, axis_name="dp", bits=8)
-        fm = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                               out_specs=(P("dp"), P("dp"))))
+        fm = jax.jit(jax.shard_map(f, mesh=mesh,
+                                   in_specs=(P("dp"), P("dp")),
+                                   out_specs=(P("dp"), P("dp"))))
         rng = np.random.default_rng(0)
         g = rng.normal(size=(8, 16, 32)).astype(np.float32)
         e = np.zeros_like(g)
@@ -99,12 +99,12 @@ def test_error_feedback_reduces_bias():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
-        from repro.distributed.sharding import shard_map
         mesh = jax.make_mesh((4,), ("dp",))
         def f(g, e):
             return compressed_psum(g, e, axis_name="dp", bits=8)
-        fm = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                               out_specs=(P("dp"), P("dp"))))
+        fm = jax.jit(jax.shard_map(f, mesh=mesh,
+                                   in_specs=(P("dp"), P("dp")),
+                                   out_specs=(P("dp"), P("dp"))))
         rng = np.random.default_rng(1)
         g = rng.normal(size=(4, 8, 8)).astype(np.float32)  # constant grads
         e = np.zeros_like(g)

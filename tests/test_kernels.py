@@ -141,3 +141,44 @@ def test_grouped_dequant_matmul_single_group(eff):
     assert got.dtype == jnp.bfloat16
     assert np.array_equal(np.asarray(got, np.float32),
                           np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu", "gpu"])
+def test_pallas_mode_follows_backend(monkeypatch, backend):
+    """Kernels interpret only on the CPU and run compiled on TPU; any other
+    backend raises instead of silently interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if backend == "gpu":
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.act_quant_pallas(jnp.ones((8, 128), jnp.float32))
+    else:
+        assert ops._interpret() is (backend == "cpu")
+
+
+@pytest.mark.parametrize("k", [64, 4096, 12800, 14336])
+def test_act_quant_block_rows_fit_vmem(k):
+    """Whole-row blocks shrink with K to stay within the VMEM budget, and
+    a batch that fits one block stays one block."""
+    from repro.kernels.act_quant import BLOCK_BYTES, block_rows
+    bm = block_rows(4096, k)
+    assert bm % 8 == 0 and 8 <= bm <= 128
+    assert bm * k * 4 <= BLOCK_BYTES
+    assert block_rows(5, k) == 5
+
+
+def test_act_quant_multi_block_at_d_ff_matches_ref():
+    """At K=12800 the row block (40) no longer covers the batch: the padded
+    multi-block run is bit-identical to the unblocked oracle, for one width
+    and for per-row widths."""
+    rng = np.random.default_rng(3)
+    x = jnp.asarray((rng.normal(size=(100, 12800)) * 2).astype(np.float32))
+    q, s = ops.act_quant_pallas(x, a_bits=8)
+    qr, sr = ref.act_quant_ref(x, bits=8)
+    assert np.array_equal(np.asarray(q), np.asarray(qr))
+    assert np.array_equal(np.asarray(s), np.asarray(sr))
+    groups = ((60, LayerPrecision(8, 8, backend="pallas")),
+              (40, LayerPrecision(4, 4, backend="pallas")))
+    got = ops._quantize_activations_rows(x, groups, None, use_pallas=True)
+    want = ops._quantize_activations_rows(x, groups, None, use_pallas=False)
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))

@@ -98,7 +98,6 @@ def test_compressed_psum_scale_jit_stable():
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.distributed.compression import compressed_psum
-    from repro.distributed.sharding import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape((1,)), ("dp",))
     rng = np.random.default_rng(8)
@@ -108,8 +107,8 @@ def test_compressed_psum_scale_jit_stable():
     def body(g, e):
         return compressed_psum(g, e, axis_name="dp")
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                   out_specs=(P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     me, ee = fn(g, err)
     mj, ej = jax.jit(fn)(g, err)
     # The wire-visible quantities (shared scale, integer sum -> mean grad)

@@ -275,6 +275,13 @@ def uniform_schedule(tiers: Dict[str, tuple],
         for name, (w, a) in tiers.items()}, kv_tiers=kv_tiers)
 
 
+def format_group_layout(layout) -> str:
+    """Stable label text of a mixed-tier group layout, the ``(tier, rows)``
+    runs of a tier-sorted decode batch:
+    ``(("8/8", 2), ("4/4", 1))`` -> ``"8/8x2+4/4x1"``."""
+    return "+".join(f"{tier}x{rows}" for tier, rows in layout)
+
+
 def allocate_bits_by_sensitivity(sensitivities: Dict[str, float],
                                  param_counts: Dict[str, int],
                                  avg_bits: float,

@@ -58,7 +58,6 @@ sampling (deterministic across eager/jit and mesh widths):
 from __future__ import annotations
 
 import argparse
-import json
 import time
 from typing import Any, Optional
 
@@ -75,7 +74,7 @@ from repro.serve import (BatchServeEngine, Request, ServeEngine, SLOPolicy,
                          prepare_params)
 from repro.serve.engine import params_prepared
 from repro.serve.handle import RequestStatus
-from repro.telemetry import Telemetry, serve_report, write_json
+from repro.telemetry import Telemetry, serve_report, write_json, xplane
 
 
 def build_engine(model: LM, params: Any, *, policy: PrecisionPolicy,
@@ -213,11 +212,13 @@ def main(argv=None):
                     help="write the dual-clock span trace as Chrome "
                          "trace-event JSON (loadable in Perfetto / "
                          "chrome://tracing)")
-    ap.add_argument("--profile", action="store_true",
-                    help="opt-in device timing: fence each prefill/decode-"
-                         "chunk/spec-round dispatch (block_until_ready) and "
-                         "report per-phase device seconds — bit-identical "
-                         "output, adds host syncs")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the serve loop "
+                         "under DIR (device time of each program, the "
+                         "model's layer scopes and the engine's serve.* "
+                         "spans on one clock; TensorBoard/XProf or "
+                         "repro.telemetry.xplane read it) and print its "
+                         "summary — bit-identical output")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -346,7 +347,7 @@ def main(argv=None):
     # off contract matters for the library; a demo CLI can afford the
     # hooks) — the end-of-run report, --metrics and --trace-out all read
     # from it.
-    tele = Telemetry(profile=args.profile)
+    tele = Telemetry()
     scheduler_policy = None
     if args.slo:
         # Rules-aware tier pricing: searched schedules (per-layer rule
@@ -428,6 +429,8 @@ def main(argv=None):
                    if r.deadline is not None and r.deadline <= urgent_deadline] \
         if args.preempt or args.shed else []
     held = {r.uid for r in urgent_tail}
+    if args.profile_dir:
+        jax.profiler.start_trace(args.profile_dir)
     handles = [engine.submit(r) for r in reqs if r.uid not in held]
     migrated = None
     events = 0
@@ -448,6 +451,8 @@ def main(argv=None):
                           f"{len(h.tokens)} tokens (clock {engine.clock:.0f})")
                     break
     dt = time.time() - t0
+    if args.profile_dir:
+        jax.profiler.stop_trace()
     if args.migrate_demo and migrated is None:
         print("migrate-demo: no request lived long enough to migrate — "
               "every budget fit one decode chunk; raise --max-new or "
@@ -471,16 +476,15 @@ def main(argv=None):
         shed_uids = [h.uid for h in handles
                      if h.status is RequestStatus.SHED]
         print(f"shed_uids={shed_uids}")
-    if args.profile:
-        assert tele.profiler is not None
-        print("profile: " + json.dumps(tele.profiler.snapshot()["phases"],
-                                       sort_keys=True))
+    if args.profile_dir:
+        print(f"profile: wrote {args.profile_dir}")
+        print(xplane.format_summary(xplane.reduce(
+            xplane.load(args.profile_dir))))
     if args.metrics is not None:
         if args.metrics == "-":
             print(tele.prometheus(), end="")
         elif args.metrics.endswith(".json"):
-            prof = tele.profiler.snapshot() if tele.profiler else None
-            write_json(args.metrics, tele.registry, prof)
+            write_json(args.metrics, tele.registry)
             print(f"metrics: wrote {args.metrics}")
         else:
             with open(args.metrics, "w") as fh:

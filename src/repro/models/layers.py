@@ -125,7 +125,14 @@ def linear(params, x, rt: Runtime, name: str, *,
     ``act_quants`` is a per-input activation-quant cache: projections that
     read the SAME tensor (q/k/v, gate/up) pass one shared dict so the batch
     is quantized once per distinct config instead of once per projection —
-    identical computation, so sharing is exact."""
+    identical computation, so sharing is exact.
+
+    Every op it adds lies under the ``linear`` named scope."""
+    with jax.named_scope("linear"):
+        return _linear(params, x, rt, name, act_quants)
+
+
+def _linear(params, x, rt: Runtime, name: str, act_quants):
     w = params["w"]
     if isinstance(w, ops.QuantizedWeight):
         if rt.groups is not None:
@@ -217,56 +224,58 @@ def flash_attention(q, k, v, *, causal: bool = True, block_k: int = 1024,
     q_offset: absolute position of q[0] (for chunked prefill / decode).
     Returns [B, Sq, H, Dh] in q.dtype.
     """
-    b, sq, h, dh = q.shape
-    _, sk, kvh, _ = k.shape
-    g = h // kvh
-    scale = 1.0 / math.sqrt(dh)
-    if g > 1:
-        # GQA as q-head-major repeat: every tensor keeps the h axis, so TP
-        # over "model" survives (a [kvh, g] reshape would break the sharding
-        # and replicate the f32 accumulators on every device).
-        k = jnp.repeat(k, g, axis=2)
-        v = jnp.repeat(v, g, axis=2)
-    qf = q.transpose(0, 2, 1, 3).astype(jnp.float32)       # [b, h, sq, dh]
-    qf = shard(qf, "batch", "model", None, None)
+    with jax.named_scope("attention"):
+        b, sq, h, dh = q.shape
+        _, sk, kvh, _ = k.shape
+        g = h // kvh
+        scale = 1.0 / math.sqrt(dh)
+        if g > 1:
+            # GQA as q-head-major repeat: every tensor keeps the h axis, so
+            # TP over "model" survives (a [kvh, g] reshape would break the
+            # sharding and replicate the f32 accumulators on every device).
+            k = jnp.repeat(k, g, axis=2)
+            v = jnp.repeat(v, g, axis=2)
+        qf = q.transpose(0, 2, 1, 3).astype(jnp.float32)       # [b, h, sq, dh]
+        qf = shard(qf, "batch", "model", None, None)
 
-    block_k = min(block_k, sk)
-    nb = -(-sk // block_k)
-    pad = nb * block_k - sk
-    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kb = kp.reshape(b, nb, block_k, h, dh).transpose(1, 0, 2, 3, 4)
-    vb = vp.reshape(b, nb, block_k, h, dh).transpose(1, 0, 2, 3, 4)
-    kpos = jnp.arange(nb * block_k).reshape(nb, block_k)
-    qpos = q_offset + jnp.arange(sq)
+        block_k = min(block_k, sk)
+        nb = -(-sk // block_k)
+        pad = nb * block_k - sk
+        kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        kb = kp.reshape(b, nb, block_k, h, dh).transpose(1, 0, 2, 3, 4)
+        vb = vp.reshape(b, nb, block_k, h, dh).transpose(1, 0, 2, 3, 4)
+        kpos = jnp.arange(nb * block_k).reshape(nb, block_k)
+        qpos = q_offset + jnp.arange(sq)
 
-    neg = jnp.float32(-1e30)
+        neg = jnp.float32(-1e30)
 
-    def body(carry, xs):
-        acc, m, l = carry
-        kblk, vblk, kp_blk = xs
-        s = jnp.einsum("bhqd,bshd->bhqs", qf,
-                       kblk.astype(jnp.float32)) * scale
-        valid = kp_blk[None, :] < sk
-        if causal:
-            valid = valid & (qpos[:, None] >= kp_blk[None, :])
-        s = jnp.where(valid[None, None], s, neg)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhqs,bshd->bhqd", p, vblk.astype(jnp.float32))
-        return (acc_new, m_new, l_new), None
+        def body(carry, xs):
+            acc, m, l = carry
+            kblk, vblk, kp_blk = xs
+            s = jnp.einsum("bhqd,bshd->bhqs", qf,
+                           kblk.astype(jnp.float32)) * scale
+            valid = kp_blk[None, :] < sk
+            if causal:
+                valid = valid & (qpos[:, None] >= kp_blk[None, :])
+            s = jnp.where(valid[None, None], s, neg)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1)
+            acc_new = acc * alpha[..., None] + jnp.einsum(
+                "bhqs,bshd->bhqd", p, vblk.astype(jnp.float32))
+            return (acc_new, m_new, l_new), None
 
-    init = (
-        jnp.zeros((b, h, sq, dh), jnp.float32),
-        jnp.full((b, h, sq), neg),
-        jnp.zeros((b, h, sq), jnp.float32),
-    )
-    (acc, m, l), _ = jax.lax.scan(jax.checkpoint(body), init, (kb, vb, kpos))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+        init = (
+            jnp.zeros((b, h, sq, dh), jnp.float32),
+            jnp.full((b, h, sq), neg),
+            jnp.zeros((b, h, sq), jnp.float32),
+        )
+        (acc, m, l), _ = jax.lax.scan(jax.checkpoint(body), init,
+                                      (kb, vb, kpos))
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 # ------------------------------------------------------------------ KV cache
@@ -495,19 +504,20 @@ class KVCache:
         lengths — used for right-padded prefill, where ``S_new`` is the
         padded length but only the first ``new_length[b]`` positions of slot
         ``b`` are real tokens."""
-        idx = (0, start, 0, 0)
-        ln = self._lengths_after(start, k_new.shape[1], new_length)
-        kq, ks = self._encode(k_new)
-        vq, vs = self._encode(v_new)
-        k = jax.lax.dynamic_update_slice(self.k, kq, idx)
-        v = jax.lax.dynamic_update_slice(self.v, vq, idx)
-        if ks is None:
-            return dataclasses.replace(self, k=k, v=v, length=ln)
-        return dataclasses.replace(
-            self, k=k, v=v,
-            k_scale=jax.lax.dynamic_update_slice(self.k_scale, ks, idx),
-            v_scale=jax.lax.dynamic_update_slice(self.v_scale, vs, idx),
-            length=ln)
+        with jax.named_scope("kv_write"):
+            idx = (0, start, 0, 0)
+            ln = self._lengths_after(start, k_new.shape[1], new_length)
+            kq, ks = self._encode(k_new)
+            vq, vs = self._encode(v_new)
+            k = jax.lax.dynamic_update_slice(self.k, kq, idx)
+            v = jax.lax.dynamic_update_slice(self.v, vq, idx)
+            if ks is None:
+                return dataclasses.replace(self, k=k, v=v, length=ln)
+            return dataclasses.replace(
+                self, k=k, v=v,
+                k_scale=jax.lax.dynamic_update_slice(self.k_scale, ks, idx),
+                v_scale=jax.lax.dynamic_update_slice(self.v_scale, vs, idx),
+                length=ln)
 
     def append(self, k_new, v_new, active=None) -> "KVCache":
         """Masked per-slot decode write: one token per slot at that slot's
@@ -515,28 +525,30 @@ class KVCache:
         positions).  Slots with ``active[b] == False`` are left untouched:
         neither their K/V rows nor their lengths move, so a finished slot's
         cache is frozen until the scheduler reuses it."""
-        b = self.k.shape[0]
-        if active is None:
-            active = jnp.ones((b,), bool)
-        active = active & (self.length < self.k.shape[1])   # never overflow
-        idx = jnp.arange(b)
-        pos = jnp.clip(self.length, 0, self.k.shape[1] - 1)
+        with jax.named_scope("kv_write"):
+            b = self.k.shape[0]
+            if active is None:
+                active = jnp.ones((b,), bool)
+            # never overflow
+            active = active & (self.length < self.k.shape[1])
+            idx = jnp.arange(b)
+            pos = jnp.clip(self.length, 0, self.k.shape[1] - 1)
 
-        def put(buf, val):
-            cur = buf[idx, pos]
-            val = jnp.where(active[(...,) + (None,) * (val.ndim - 1)],
-                            val.astype(buf.dtype), cur)
-            return buf.at[idx, pos].set(val)
+            def put(buf, val):
+                cur = buf[idx, pos]
+                val = jnp.where(active[(...,) + (None,) * (val.ndim - 1)],
+                                val.astype(buf.dtype), cur)
+                return buf.at[idx, pos].set(val)
 
-        ln = self.length + active.astype(self.length.dtype)
-        kq, ks = self._encode(k_new)
-        vq, vs = self._encode(v_new)
-        k, v = put(self.k, kq[:, 0]), put(self.v, vq[:, 0])
-        if ks is None:
-            return dataclasses.replace(self, k=k, v=v, length=ln)
-        return dataclasses.replace(
-            self, k=k, v=v, k_scale=put(self.k_scale, ks[:, 0]),
-            v_scale=put(self.v_scale, vs[:, 0]), length=ln)
+            ln = self.length + active.astype(self.length.dtype)
+            kq, ks = self._encode(k_new)
+            vq, vs = self._encode(v_new)
+            k, v = put(self.k, kq[:, 0]), put(self.v, vq[:, 0])
+            if ks is None:
+                return dataclasses.replace(self, k=k, v=v, length=ln)
+            return dataclasses.replace(
+                self, k=k, v=v, k_scale=put(self.k_scale, ks[:, 0]),
+                v_scale=put(self.v_scale, vs[:, 0]), length=ln)
 
     def requantize(self, kv_bits_new) -> "KVCache":
         """Re-encode the stored K/V at new per-slot tier codes (mixed mode
@@ -553,17 +565,18 @@ class KVCache:
         migrated lane is bit-identical to quantizing the dequantized cache
         directly at the target precision.  Lengths and all other slots'
         data are untouched (callers migrate one slot via a slot view)."""
-        if not self.mixed:
-            raise ValueError("requantize() needs the mixed per-slot KV "
-                             "arena (kv_bits tier codes)")
-        k, v = self.read(jnp.bfloat16)
-        out = dataclasses.replace(
-            self, kv_bits=jnp.broadcast_to(
-                jnp.asarray(kv_bits_new, self.kv_bits.dtype),
-                self.kv_bits.shape))
-        kq, ks = out._encode(k)
-        vq, vs = out._encode(v)
-        return dataclasses.replace(out, k=kq, v=vq, k_scale=ks, v_scale=vs)
+        with jax.named_scope("kv_write"):
+            if not self.mixed:
+                raise ValueError("requantize() needs the mixed per-slot KV "
+                                 "arena (kv_bits tier codes)")
+            k, v = self.read(jnp.bfloat16)
+            out = dataclasses.replace(
+                self, kv_bits=jnp.broadcast_to(
+                    jnp.asarray(kv_bits_new, self.kv_bits.dtype),
+                    self.kv_bits.shape))
+            kq, ks = out._encode(k)
+            vq, vs = out._encode(v)
+            return dataclasses.replace(out, k=kq, v=vq, k_scale=ks, v_scale=vs)
 
     def read(self, dtype=jnp.bfloat16):
         """Dequantized (K, V) views of the whole arena.
@@ -575,19 +588,22 @@ class KVCache:
         but not another's — a one-ulp reassociation that breaks mixed-vs-
         fixed-precision bit-identity.  Dense bf16 reads have no continuous
         scale and stay unbarriered."""
-        if self.mixed:
-            return jax.lax.optimization_barrier(
-                (self._decode_mixed(self.k, self.k_scale, dtype),
-                 self._decode_mixed(self.v, self.v_scale, dtype)))
-        if self.quantized:
-            k = self.k.astype(dtype) * self.k_scale.astype(dtype)
-            v = self.v.astype(dtype) * self.v_scale.astype(dtype)
-            return jax.lax.optimization_barrier((k, v))
-        if self.packed4:
-            k = _unpack_int4(self.k).astype(dtype) * self.k_scale.astype(dtype)
-            v = _unpack_int4(self.v).astype(dtype) * self.v_scale.astype(dtype)
-            return jax.lax.optimization_barrier((k, v))
-        return self.k.astype(dtype), self.v.astype(dtype)
+        with jax.named_scope("attention"):
+            if self.mixed:
+                return jax.lax.optimization_barrier(
+                    (self._decode_mixed(self.k, self.k_scale, dtype),
+                     self._decode_mixed(self.v, self.v_scale, dtype)))
+            if self.quantized:
+                k = self.k.astype(dtype) * self.k_scale.astype(dtype)
+                v = self.v.astype(dtype) * self.v_scale.astype(dtype)
+                return jax.lax.optimization_barrier((k, v))
+            if self.packed4:
+                k = _unpack_int4(self.k).astype(dtype) \
+                    * self.k_scale.astype(dtype)
+                v = _unpack_int4(self.v).astype(dtype) \
+                    * self.v_scale.astype(dtype)
+                return jax.lax.optimization_barrier((k, v))
+            return self.k.astype(dtype), self.v.astype(dtype)
 
 
 jax.tree_util.register_dataclass(
@@ -603,27 +619,29 @@ def decode_attention(q, cache: KVCache):
     dtype (bf16/int8-dequant) with f32 accumulation via
     preferred_element_type, so the big cache tensors are never materialized
     in f32 and the head_dim contraction runs sharded (§Perf decode iters)."""
-    b, sq, h, dh = q.shape
-    k, v = cache.read(q.dtype)
-    sk = k.shape[1]
-    kvh = k.shape[2]
-    g = h // kvh
-    scale = 1.0 / math.sqrt(dh)
-    qg = q.reshape(b, sq, kvh, g, dh)
-    # Match the cache's head_dim TP sharding: the contraction then runs as
-    # sharded partial sums + a 33MB score psum instead of all-gathering the
-    # multi-GB K (§Perf decode iteration).
-    qg = shard(qg, "batch", None, None, None, "model")
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(sk)
-    # Per-slot length mask: slot b attends only its own filled positions.
-    valid = pos[None, :] < cache.length[:, None]            # [B, Smax]
-    s = jnp.where(valid[:, None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(q.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dh).astype(q.dtype)
+    with jax.named_scope("attention"):
+        b, sq, h, dh = q.shape
+        k, v = cache.read(q.dtype)
+        sk = k.shape[1]
+        kvh = k.shape[2]
+        g = h // kvh
+        scale = 1.0 / math.sqrt(dh)
+        qg = q.reshape(b, sq, kvh, g, dh)
+        # Match the cache's head_dim TP sharding: the contraction then runs as
+        # sharded partial sums + a 33MB score psum instead of all-gathering the
+        # multi-GB K (§Perf decode iteration).
+        qg = shard(qg, "batch", None, None, None, "model")
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        pos = jnp.arange(sk)
+        # Per-slot length mask: slot b attends only its own filled positions.
+        valid = pos[None, :] < cache.length[:, None]            # [B, Smax]
+        s = jnp.where(valid[:, None, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(q.dtype), v,
+                         preferred_element_type=jnp.float32)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dh)
+        return out.astype(q.dtype)
 
 
 # --------------------------------------------------------------- GQA attention

@@ -67,13 +67,16 @@ class LM:
         return jnp.take(params["embed"]["emb"], tokens, axis=0)
 
     def _head(self, params, x, rt: layers.Runtime):
-        x = layers.rmsnorm(params["final_norm"], x)
-        if self.cfg.tie_embeddings:
-            w = params["embed"]["emb"].T
-            logits = jnp.matmul(x, w.astype(x.dtype))
-        else:
-            logits = layers.linear(params["lm_head"], x, rt, "lm_head")
-        return shard(logits, "batch", None, "model")
+        # One named scope for the final norm and either head (the untied
+        # head's ``linear`` scope sits inside it).
+        with jax.named_scope("lm_head"):
+            x = layers.rmsnorm(params["final_norm"], x)
+            if self.cfg.tie_embeddings:
+                w = params["embed"]["emb"].T
+                logits = jnp.matmul(x, w.astype(x.dtype))
+            else:
+                logits = layers.linear(params["lm_head"], x, rt, "lm_head")
+            return shard(logits, "batch", None, "model")
 
     def _period_body(self, blk_params, x, rt, caches=None, seq_lengths=None,
                      active=None, verify_window=False):

@@ -85,7 +85,7 @@ import numpy.typing as npt
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpoint as checkpoint_lib
-from repro.core.policy import PrecisionPolicy
+from repro.core.policy import PrecisionPolicy, format_group_layout
 from repro.distributed import sharding_rules, tp_serve
 from repro.kernels import ops
 from repro.models.layers import Runtime
@@ -119,6 +119,12 @@ PREPARE_CALLS = 0
 # would round in different places, and on a TPU v5e their tokens diverged.
 COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 serve_jit = functools.partial(jax.jit, compiler_options=COMPILER_OPTIONS)
+
+# The engine's host phases as profiler spans (``serve.step``,
+# ``serve.decode``, ``serve.wait_decode``, ...; docs/observability.md): they
+# land in a ``jax.profiler`` trace on the clock of the device ops, and with
+# no profiler session running each costs a flag check.
+span = jax.profiler.TraceAnnotation
 
 
 def prepare_params(params: Any, policy: PrecisionPolicy, model: LM,
@@ -485,13 +491,11 @@ class ServeEngine(_DeferredErrors):
         # never imports the telemetry package).  The contract: EVERY hook
         # call below is guarded by ``telemetry is not None`` and the engine
         # itself never fences — a telemetry-None engine runs the decode hot
-        # loop with zero added host syncs, allocations, or hook calls.
+        # loop with zero added host syncs, allocations, or hook calls.  The
+        # profiler spans (``span``) are there either way.
         self.telemetry = telemetry
         if telemetry is not None:
-            telemetry.attach_engine(
-                num_slots=max_batch, schedule=self.schedule,
-                mac_counts=model.cfg.quant_layer_macs()
-                if self.schedule is not None else None)
+            telemetry.attach_engine(num_slots=max_batch)
         # Group-layout memo: slot-tier vector -> (groups, perm).  Recurring
         # mixed-batch layouts (the steady state) skip the per-step Python
         # sort; hits/misses are surfaced on EngineStats.
@@ -547,16 +551,18 @@ class ServeEngine(_DeferredErrors):
             if tp is not None:
                 rt_eff = dataclasses.replace(rt_eff, tp=tp)
             sub = slots_lib.slot_view(caches, slot)
-            sub = jax.tree.map(jnp.zeros_like, sub)     # per-slot reset
+            with jax.named_scope("slot_io"):  # per-slot reset
+                sub = jax.tree.map(jnp.zeros_like, sub)
             if mixed_kv:
                 sub = slots_lib.fill_kv_tier(sub, kv_code)
             logits, sub = self.model.prefill(
                 params, rt_eff, sub, tokens=tokens,
                 seq_lengths=length.reshape(1))
             caches = slots_lib.slot_write(caches, sub, slot)
-            tok, _ = sampling_lib.sample_tokens(
-                logits[:, -1], key[None, :], jnp.zeros((1,), jnp.int32),
-                temp.reshape(1), topk.reshape(1))
+            with jax.named_scope("sample"):
+                tok, _ = sampling_lib.sample_tokens(
+                    logits[:, -1], key[None, :], jnp.zeros((1,), jnp.int32),
+                    temp.reshape(1), topk.reshape(1))
             return tok[0], caches
 
         def decode_chunk_fn(params: Any, caches: Any, tok: Any,
@@ -602,8 +608,9 @@ class ServeEngine(_DeferredErrors):
                     logits, caches = self.model.decode_step(
                         params, rt_eff, caches, tokens=tok[:, None],
                         active=active)
-                    nxt = jnp.argmax(logits[:, -1],
-                                     axis=-1).astype(jnp.int32)
+                    with jax.named_scope("sample"):
+                        nxt = jnp.argmax(logits[:, -1],
+                                         axis=-1).astype(jnp.int32)
                     tok = jnp.where(active, nxt, tok)
                     remaining = remaining - active.astype(jnp.int32)
                     return (tok, caches, remaining), (tok, active)
@@ -620,8 +627,10 @@ class ServeEngine(_DeferredErrors):
                 logits, caches = self.model.decode_step(
                     params, rt_eff, caches, tokens=tok[:, None],
                     active=active)
-                nxt, draws = sampling_lib.sample_tokens(
-                    logits[:, -1], keys, draws, temp, topk, active=active)
+                with jax.named_scope("sample"):
+                    nxt, draws = sampling_lib.sample_tokens(
+                        logits[:, -1], keys, draws, temp, topk,
+                        active=active)
                 tok = jnp.where(active, nxt, tok)
                 remaining = remaining - active.astype(jnp.int32)
                 return (tok, caches, remaining, draws), (tok, active)
@@ -671,10 +680,11 @@ class ServeEngine(_DeferredErrors):
                 logits, caches = self.model.decode_step(
                     params, rt_draft, caches, tokens=tok[:, None],
                     active=active)
-                row = logits[:, -1]
-                qp = sampling_lib.sampling_probs(row, temp, topk)
-                nxt, draws = sampling_lib.sample_tokens(
-                    row, keys, draws, temp, topk, active=active)
+                with jax.named_scope("sample"):
+                    row = logits[:, -1]
+                    qp = sampling_lib.sampling_probs(row, temp, topk)
+                    nxt, draws = sampling_lib.sample_tokens(
+                        row, keys, draws, temp, topk, active=active)
                 tok = jnp.where(active, nxt, tok)
                 # Spec slots draft beyond their budget accounting: they
                 # spend ``remaining`` only at emission (verify) time.
@@ -696,14 +706,15 @@ class ServeEngine(_DeferredErrors):
                 params, rt_verify, caches, tokens=window, active=spec_mask)
 
             batch, width = window.shape                  # width == k + 1
-            p = sampling_lib.sampling_probs(
-                vlogits.reshape(batch * width, -1),
-                jnp.repeat(temp, width),
-                jnp.repeat(topk, width)).reshape(batch, width, -1)
-            q = jnp.swapaxes(qps, 0, 1)                       # [B, k, V]
-            m = spec_lib.accept_counts(drafts, q, p, keys, draws)
-            corr = spec_lib.correction_tokens(q, p, m, keys, draws)
-            emit = spec_lib.emission_window(drafts, corr, m)
+            with jax.named_scope("sample"):            # acceptance
+                p = sampling_lib.sampling_probs(
+                    vlogits.reshape(batch * width, -1),
+                    jnp.repeat(temp, width),
+                    jnp.repeat(topk, width)).reshape(batch, width, -1)
+                q = jnp.swapaxes(qps, 0, 1)                   # [B, k, V]
+                m = spec_lib.accept_counts(drafts, q, p, keys, draws)
+                corr = spec_lib.correction_tokens(q, p, m, keys, draws)
+                emit = spec_lib.emission_window(drafts, corr, m)
             e = jnp.where(spec_mask, jnp.minimum(m + 1, remaining), 0)
 
             # Rollback: rewind the KV lengths of rejected window positions
@@ -1003,6 +1014,10 @@ class ServeEngine(_DeferredErrors):
         SHED state (fail fast beats a guaranteed miss) — or, with
         ``auto_tier``, downtiered to the fastest-fitting tier (counted in
         ``EngineStats.tier_autoselects`` like any deadline-driven retag)."""
+        with span("serve.submit", uid=request.uid):
+            return self._submit(request)
+
+    def _submit(self, request: Request) -> RequestHandle:
         _validate_request(request, self.max_len, self._seen_uids)
         if self.schedule is None:
             if request.tier is not None:
@@ -1111,7 +1126,6 @@ class ServeEngine(_DeferredErrors):
         slot = handle.slot
         assert slot is not None
         kv_migrated = False
-        t0 = self.telemetry.wall() if self.telemetry is not None else 0.0
         if self._mixed_kv:
             new_code = self.schedule.kv_code_for(tier)
             if new_code != self.schedule.kv_code_for(old):
@@ -1125,8 +1139,7 @@ class ServeEngine(_DeferredErrors):
         if self.telemetry is not None:
             self.telemetry.on_migrate(
                 uid=handle.uid, old_tier=old, new_tier=tier, kv=kv_migrated,
-                ticks=self.clock, t0=t0 if kv_migrated else None,
-                fence=self.arena.caches if kv_migrated else None)
+                ticks=self.clock)
         self._sync_telemetry()
 
     # ------------------------------------------------------------- preemption
@@ -1164,6 +1177,10 @@ class ServeEngine(_DeferredErrors):
         from inside ``step()`` (e.g. an ``on_token`` callback) raises —
         mid-round the device cache has already advanced past the host
         token bookkeeping, so a snapshot there would tear the state."""
+        with span("serve.preempt", uid=uid):
+            return self._preempt(uid)
+
+    def _preempt(self, uid: int) -> SuspendedState:
         if self._in_round:
             raise RuntimeError(
                 "preempt() called from inside a scheduling round (e.g. an "
@@ -1416,39 +1433,47 @@ class ServeEngine(_DeferredErrors):
                 continue
             self._auto_select_tier(req)
             padded, plen = self._bucket_pad(np.asarray(req.prompt))
-            kv_code = self.schedule.kv_code_for(req.tier) \
-                if self._mixed_kv else 0
-            self._load_sampling_state(slot, req, draws=0)
-            t0 = self.telemetry.wall() if self.telemetry is not None else 0.0
+            with span("serve.admit", uid=req.uid, tier=req.tier,
+                      prompt_len=plen, bucket=padded.shape[1]):
+                events.append(self._admit(slot, req, padded, plen))
+        return events
+
+    def _admit(self, slot: int, req: Request, padded: Any,
+               plen: int) -> TokenEvent:
+        """Prefill one admitted request into ``slot`` and emit its first
+        token."""
+        kv_code = self.schedule.kv_code_for(req.tier) \
+            if self._mixed_kv else 0
+        self._load_sampling_state(slot, req, draws=0)
+        with span("serve.prefill", tier=req.tier, prompt_len=plen,
+                  bucket=padded.shape[1]):
             tok, self.arena.caches = self._prefill_slot(
                 self.params, self.arena.caches, jnp.int32(slot),
                 jnp.asarray(padded), jnp.int32(plen), jnp.int32(kv_code),
                 jnp.asarray(self._key[slot]),
                 jnp.float32(self._temp[slot]),
                 jnp.int32(self._topk[slot]), tier=req.tier)
-            self.arena.tiers[slot] = req.tier
-            self.stats.prefills += 1
-            self.stats.prefill_tokens += plen
-            if self.telemetry is not None:
-                self.telemetry.on_prefill(
-                    uid=req.uid, tier=req.tier, prompt_len=plen, t0=t0,
-                    ticks=self.clock, fence=self.arena.caches)
-            # The first token was draw event 0 (sampled rows only).
-            if self._temp[slot] > 0.0:
-                self._draws[slot] = 1
-            self._slice_start[slot] = self.clock
+        self.arena.tiers[slot] = req.tier
+        self.stats.prefills += 1
+        self.stats.prefill_tokens += plen
+        # The first token was draw event 0 (sampled rows only).
+        if self._temp[slot] > 0.0:
+            self._draws[slot] = 1
+        self._slice_start[slot] = self.clock
+        with span("serve.wait_first_token"):
             first = int(tok)
-            state = self.scheduler.slots[slot]
-            assert state is not None
-            self.handles[req.uid]._mark_admitted(slot, self.clock)
-            if self.telemetry is not None:
-                self.telemetry.on_admit(self.handles[req.uid], slot=slot,
-                                        ticks=self.clock)
-            events.append(self._emit_token(state, first,
-                                           req.tier))  # token 1 of max_new
-            self._tok[slot] = first
-            self._remaining[slot] = state.remaining
-        return events
+        state = self.scheduler.slots[slot]
+        assert state is not None
+        self.handles[req.uid]._mark_admitted(slot, self.clock)
+        if self.telemetry is not None:
+            self.telemetry.on_admit(self.handles[req.uid], slot=slot,
+                                    ticks=self.clock)
+        with span("serve.emit"):
+            event = self._emit_token(state, first,
+                                     req.tier)  # token 1 of max_new
+        self._tok[slot] = first
+        self._remaining[slot] = state.remaining
+        return event
 
     def _auto_select_tier(self, req: Request) -> None:
         """Deadline-aware tier auto-selection at admission
@@ -1536,19 +1561,20 @@ class ServeEngine(_DeferredErrors):
         round's batch; ``_in_round`` then pins preemption out for the rest
         of the round (an ``on_token`` callback calling ``preempt`` would
         tear host state from the already-advanced device cache)."""
-        if self.schedule is not None and not self.mixed_tiers:
-            if not self.scheduler.occupied():
-                if self._active_tier is not None:  # keep across idle steps
-                    self._last_tier = self._active_tier
-                self._active_tier = None           # batch drained: re-tier
-        self._time_slice_preempt()
-        self._policy_preempt()
-        self._in_round = True
-        try:
-            return self._step_round()
-        finally:
-            self._in_round = False
-            self._sync_telemetry()
+        with span("serve.step"):
+            if self.schedule is not None and not self.mixed_tiers:
+                if not self.scheduler.occupied():
+                    if self._active_tier is not None:  # keep across idles
+                        self._last_tier = self._active_tier
+                    self._active_tier = None       # batch drained: re-tier
+            self._time_slice_preempt()
+            self._policy_preempt()
+            self._in_round = True
+            try:
+                return self._step_round()
+            finally:
+                self._in_round = False
+                self._sync_telemetry()
 
     def _time_slice_preempt(self) -> None:
         """Time-slice fairness (``SLOPolicy(time_slice=N)``): between
@@ -1597,35 +1623,34 @@ class ServeEngine(_DeferredErrors):
         # (keyed per distinct length: at most decode_chunk jit entries).
         n_steps = int(min(self.decode_chunk,
                           max(s.remaining for _, s in occupied)))
-        tele = self.telemetry
         groups: Optional[GroupLayout]
-        if self.schedule is not None and self.mixed_tiers:
-            groups, perm = self._group_layout()
-            tier = None
-            # A profiling telemetry wants the per-layout dispatch counts
-            # too (same jaxpr counting, same memo dict).
-            want_counts = self.count_dispatches or (
-                tele is not None and tele.profiler is not None)
-            if want_counts and groups not in self.stats.decode_dispatches:
-                self.stats.decode_dispatches[groups] = \
-                    self.decode_dispatch_count(groups=groups)
-        else:
-            groups, perm = None, np.zeros((self.max_batch,), np.int32)
-            tier = self._active_tier
-        t0 = tele.wall() if tele is not None else 0.0
-        ticks0 = self.clock
-        (self.arena.caches, tok, remaining, draws, toks, actives) = \
-            self._decode_chunk(self.params, self.arena.caches,
-                               jnp.asarray(self._tok),
-                               jnp.asarray(self._remaining),
-                               jnp.asarray(perm), n_steps=n_steps,
-                               tier=tier, groups=groups,
-                               sampling=self._sampling_args())
-        self._tok = np.array(tok)            # copies: host arrays stay writable
-        self._remaining = np.array(remaining)
-        self._draws = np.array(draws)
-        toks = np.asarray(toks)                   # [n_steps, B]
-        actives = np.asarray(actives)
+        with span("serve.decode", n_steps=n_steps) as sp:
+            if self.schedule is not None and self.mixed_tiers:
+                groups, perm = self._group_layout()
+                tier = None
+                sp.set_metadata(layout=format_group_layout(groups))
+                if self.count_dispatches \
+                        and groups not in self.stats.decode_dispatches:
+                    self.stats.decode_dispatches[groups] = \
+                        self.decode_dispatch_count(groups=groups)
+            else:
+                groups, perm = None, np.zeros((self.max_batch,), np.int32)
+                tier = self._active_tier
+                sp.set_metadata(tier=tier)
+            (self.arena.caches, tok, remaining, draws, toks, actives) = \
+                self._decode_chunk(self.params, self.arena.caches,
+                                   jnp.asarray(self._tok),
+                                   jnp.asarray(self._remaining),
+                                   jnp.asarray(perm), n_steps=n_steps,
+                                   tier=tier, groups=groups,
+                                   sampling=self._sampling_args())
+        with span("serve.wait_decode"):
+            # copies: host arrays stay writable
+            self._tok = np.array(tok)
+            self._remaining = np.array(remaining)
+            self._draws = np.array(draws)
+            toks = np.asarray(toks)               # [n_steps, B]
+            actives = np.asarray(actives)
         self.stats.decode_chunks += 1
         self.stats.decode_steps += n_steps
         self.stats.decode_slot_steps += int(actives.sum())
@@ -1644,17 +1669,6 @@ class ServeEngine(_DeferredErrors):
                 t = self.arena.tiers[slot] if self.mixed_tiers else tier
                 assert t is not None
                 tk[t] = tk.get(t, 0) + int(actives[:, slot].sum())
-        if tele is not None:
-            # Free lanes carry tier None (priced at the schedule default —
-            # the dense batch dispatches them either way).
-            lanes = [(self.arena.tiers[s], int(actives[:, s].sum()))
-                     for s in range(self.max_batch)]
-            tele.on_decode_chunk(
-                t0=t0, ticks0=ticks0, ticks_end=self.clock,
-                n_steps=n_steps, lanes=lanes, groups=groups,
-                fence=self.arena.caches,
-                dispatches=self.stats.decode_dispatches.get(groups)
-                if groups is not None else None)
         # Emission in true stream order (step-major): per-request order is
         # identical to the historical slot-major loop.  Event tiers are the
         # tiers the chunk DISPATCHED at (a set_tier from a callback must
@@ -1665,12 +1679,13 @@ class ServeEngine(_DeferredErrors):
             etier = {s_: self.arena.tiers[s_] for s_, _ in occupied}
         else:
             etier = {s_: tier for s_, _ in occupied}
-        for s in range(n_steps):
-            for slot, state in occupied:
-                if actives[s, slot]:
-                    events.append(self._emit_token(state, int(toks[s, slot]),
-                                                   etier[slot]))
-        self._release_done()
+        with span("serve.emit"):
+            for s in range(n_steps):
+                for slot, state in occupied:
+                    if actives[s, slot]:
+                        events.append(self._emit_token(
+                            state, int(toks[s, slot]), etier[slot]))
+            self._release_done()
         self._raise_deferred()
         return events
 
@@ -1698,27 +1713,28 @@ class ServeEngine(_DeferredErrors):
         for slot, s in spec_states:
             spec_mask[slot] = True
             draft_tiers[slot] = s.request.spec.draft_tier
-        draft_groups, perm_d = self._group_layout(tiers=draft_tiers)
-        verify_groups, perm_v = self._group_layout()
-        tele = self.telemetry
-        t0 = tele.wall() if tele is not None else 0.0
-        ticks0 = self.clock
-        (self.arena.caches, tok, remaining, draws, dtoks, dact, win, e,
-         m) = self._spec_round(
-            self.params, self.arena.caches, jnp.asarray(self._tok),
-            jnp.asarray(self._remaining), jnp.asarray(perm_d),
-            jnp.asarray(perm_v), jnp.asarray(spec_mask),
-            self._sampling_args(), k=k, draft_groups=draft_groups,
-            verify_groups=verify_groups)
-        self._tok = np.array(tok)
-        self._remaining = np.array(remaining)
-        self._draws = np.array(draws)
-        dtoks = np.asarray(dtoks)                       # [k, B]
-        dact = np.asarray(dact)                         # [k, B]
-        win = np.asarray(win)                           # [B, k+1]
-        e = np.asarray(e)
-        m = np.asarray(m)
         n_spec = len(spec_states)
+        with span("serve.spec_round", k=k, n_spec=n_spec) as sp:
+            draft_groups, perm_d = self._group_layout(tiers=draft_tiers)
+            verify_groups, perm_v = self._group_layout()
+            sp.set_metadata(draft_layout=format_group_layout(draft_groups),
+                            layout=format_group_layout(verify_groups))
+            (self.arena.caches, tok, remaining, draws, dtoks, dact, win, e,
+             m) = self._spec_round(
+                self.params, self.arena.caches, jnp.asarray(self._tok),
+                jnp.asarray(self._remaining), jnp.asarray(perm_d),
+                jnp.asarray(perm_v), jnp.asarray(spec_mask),
+                self._sampling_args(), k=k, draft_groups=draft_groups,
+                verify_groups=verify_groups)
+        with span("serve.wait_spec"):
+            self._tok = np.array(tok)
+            self._remaining = np.array(remaining)
+            self._draws = np.array(draws)
+            dtoks = np.asarray(dtoks)                   # [k, B]
+            dact = np.asarray(dact)                     # [k, B]
+            win = np.asarray(win)                       # [B, k+1]
+            e = np.asarray(e)
+            m = np.asarray(m)
         self.stats.decode_chunks += 1
         self.stats.decode_steps += width
         self.stats.spec_rounds += 1
@@ -1753,32 +1769,21 @@ class ServeEngine(_DeferredErrors):
                 n += int(e[slot])
             if n:
                 tk[t] = tk.get(t, 0) + n
-        if tele is not None:
-            # Spec slots are busy all k draft steps AND the verify step;
-            # plain slots decode normally through the draft phase only.
-            draft_lanes = [
-                (draft_tiers[s], k if spec_mask[s]
-                 else int(dact[:, s].sum())) for s in range(self.max_batch)]
-            verify_lanes = [(self.arena.tiers[s], 1 if spec_mask[s] else 0)
-                            for s in range(self.max_batch)]
-            tele.on_spec_round(
-                t0=t0, ticks0=ticks0, ticks_end=self.clock, k=k,
-                draft_lanes=draft_lanes, verify_lanes=verify_lanes,
-                fence=self.arena.caches, args={"n_spec": n_spec})
         # Emission: plain slots step-major through the draft phase, then
         # each spec slot's verified window (decoded AT the verify tier).
         etier = {slot: self.arena.tiers[slot] for slot, _ in occupied}
-        for s_i in range(k):
-            for slot, state in occupied:
-                if dact[s_i, slot]:
+        with span("serve.emit"):
+            for s_i in range(k):
+                for slot, state in occupied:
+                    if dact[s_i, slot]:
+                        events.append(self._emit_token(
+                            state, int(dtoks[s_i, slot]), etier[slot]))
+            for slot, state in spec_states:
+                for j in range(int(e[slot])):
                     events.append(self._emit_token(
-                        state, int(dtoks[s_i, slot]), etier[slot]))
-        for slot, state in spec_states:
-            for j in range(int(e[slot])):
-                events.append(self._emit_token(
-                    state, int(win[slot, j]), etier[slot],
-                    speculative=True))
-        self._release_done()
+                        state, int(win[slot, j]), etier[slot],
+                        speculative=True))
+            self._release_done()
         self._raise_deferred()
         return events
 
@@ -1896,13 +1901,11 @@ class BatchServeEngine(_DeferredErrors):
         self.max_len = max_len
         self.kv_bits = kv_bits
         self.stats = EngineStats()
-        # Minimal telemetry (lifecycle + stat twins; no device spans): the
-        # baseline exists for parity runs, and ``--baseline --metrics``
-        # should still export.
+        # Minimal telemetry (lifecycle + stat twins): the baseline exists
+        # for parity runs, and ``--baseline --metrics`` should still export.
         self.telemetry = telemetry
         if telemetry is not None:
-            telemetry.attach_engine(num_slots=max_batch,
-                                    schedule=rt.schedule)
+            telemetry.attach_engine(num_slots=max_batch)
         self.handles: Dict[int, RequestHandle] = {}
         self.results: Dict[int, List[int]] = {}
         self._queue: List[Request] = []
@@ -1995,18 +1998,12 @@ class BatchServeEngine(_DeferredErrors):
             prompts[i, :len(r.prompt)] = r.prompt    # right-pad
             lengths[i] = len(r.prompt)
         caches = self.model.init_cache(b, self.max_len, kv_bits=self.kv_bits)
-        t0 = self.telemetry.wall() if self.telemetry is not None else 0.0
         logits, caches = self._prefill(self.params, caches,
                                        jnp.asarray(prompts),
                                        jnp.asarray(lengths))
         self.stats.prefills += b
         self.stats.prefill_tokens += int(lengths.sum())
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        if self.telemetry is not None:
-            # One batch-wide prefill dispatch (uid -1 = whole batch).
-            self.telemetry.on_prefill(uid=-1, tier=self.tier_name,
-                                      prompt_len=int(lengths.sum()), t0=t0,
-                                      ticks=self.clock, fence=caches)
         for i, r in enumerate(batch):
             self.handles[r.uid]._mark_admitted(i, self.clock)
             if self.telemetry is not None:
@@ -2042,21 +2039,12 @@ class BatchServeEngine(_DeferredErrors):
                                           defer=self._defer_error)
                 if self.telemetry is not None:
                     self.telemetry.on_token(event, ticks=self.clock)
-        ticks0 = self.clock
-        t0 = self.telemetry.wall() if self.telemetry is not None else 0.0
         logits, a.caches = self._decode(self.params, a.caches, a.tok[:, None])
         a.tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         self.stats.decode_steps += 1
         self.stats.decode_slot_steps += len(a.batch)
         a.step_idx += 1
         if self.telemetry is not None:
-            # Every batch lane burns the step (the baseline's defining
-            # waste is visible as utilization 1.0 only while all requests
-            # are still owed tokens).
-            self.telemetry.on_decode_chunk(
-                t0=t0, ticks0=ticks0, ticks_end=self.clock, n_steps=1,
-                lanes=[(self.tier_name, 1) for _ in a.batch],
-                fence=a.caches)
             self.telemetry.sync_stats(self.stats,
                                       queue_depth=len(self._queue))
         if a.step_idx >= a.max_new:

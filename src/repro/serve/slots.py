@@ -37,9 +37,11 @@ def slot_view(caches: Any, slot: Any) -> Any:
     same view doubles as the preemption snapshot (``ServeEngine.preempt``)
     — restoring it into ANY free slot via :func:`slot_write` reproduces
     the suspended request's decode state exactly, whatever its KV tier."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=SLOT_AXIS),
-        caches)
+    with jax.named_scope("slot_io"):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1,
+                                                   axis=SLOT_AXIS),
+            caches)
 
 
 def slot_write(caches: Any, sub: Any, slot: Any) -> Any:
@@ -49,14 +51,17 @@ def slot_write(caches: Any, sub: Any, slot: Any) -> Any:
         idx = [0] * a.ndim
         idx[SLOT_AXIS] = slot
         return jax.lax.dynamic_update_slice(a, s.astype(a.dtype), tuple(idx))
-    return jax.tree.map(put, caches, sub)
+    with jax.named_scope("slot_io"):
+        return jax.tree.map(put, caches, sub)
 
 
 def slot_reset(caches: Any, slot: Any) -> Any:
     """Zero one slot's cache state (lengths included) in place of the pytree."""
-    zero = jax.tree.map(lambda a: jnp.zeros_like(
-        jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=SLOT_AXIS)), caches)
-    return slot_write(caches, zero, slot)
+    with jax.named_scope("slot_io"):
+        zero = jax.tree.map(lambda a: jnp.zeros_like(
+            jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=SLOT_AXIS)),
+            caches)
+        return slot_write(caches, zero, slot)
 
 
 def merge_slots(updated: Any, original: Any, keep_original: Any) -> Any:
@@ -142,8 +147,9 @@ def fill_kv_tier(caches: Any, code: Any) -> Any:
             return dataclasses.replace(
                 c, kv_bits=jnp.zeros_like(c.kv_bits) + code)
         return c
-    return jax.tree.map(one, caches,
-                        is_leaf=lambda c: isinstance(c, KVCache))
+    with jax.named_scope("slot_io"):
+        return jax.tree.map(one, caches,
+                            is_leaf=lambda c: isinstance(c, KVCache))
 
 
 def migrate_kv_tier(caches: Any, slot: Any, code: Any) -> Any:

@@ -111,20 +111,14 @@ def parse_prometheus(text: str) -> ParsedSamples:
     return out
 
 
-def to_json(registry: MetricsRegistry,
-            profile: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """JSON snapshot: the registry dump plus (optionally) the device
-    profiler's per-phase timings."""
-    out: Dict[str, Any] = {"metrics": registry.snapshot()}
-    if profile is not None:
-        out["profile"] = profile
-    return out
+def to_json(registry: MetricsRegistry) -> Dict[str, Any]:
+    """JSON snapshot: the registry dump."""
+    return {"metrics": registry.snapshot()}
 
 
-def write_json(path: str, registry: MetricsRegistry,
-               profile: Optional[Dict[str, Any]] = None) -> None:
+def write_json(path: str, registry: MetricsRegistry) -> None:
     with open(path, "w") as fh:
-        json.dump(to_json(registry, profile), fh, indent=2, sort_keys=True)
+        json.dump(to_json(registry), fh, indent=2, sort_keys=True)
         fh.write("\n")
 
 
@@ -156,8 +150,7 @@ def serve_report(registry: MetricsRegistry, *,
         f"decode_steps={v('serve_decode_steps'):.0f} "
         f"slot_steps={v('serve_decode_slot_steps'):.0f} "
         f"chunks={v('serve_decode_chunks'):.0f} "
-        f"slot_util={v('serve_slot_utilization'):.2f} "
-        f"modeled_cycle_util={v('serve_modeled_cycle_utilization'):.2f}",
+        f"slot_util={v('serve_slot_utilization'):.2f}",
         "latency: "
         f"ttft {_hist_line(registry, 'serve_ttft_ticks', 'ticks')}; "
         f"tpot {_hist_line(registry, 'serve_tpot_ticks', 'ticks/tok')}; "

@@ -23,10 +23,6 @@ asserts the twins stay equal after every engine op).
 Derived serving metrics (the paper's utilization story):
 
 * slot utilization — ``decode_slot_steps / (decode_steps * num_slots)``;
-* modeled-cycle utilization — useful MACs priced by
-  :func:`repro.hwmodel.energy.tier_cycles_per_token` against the cycles
-  the dispatched decode lanes occupied (see
-  :meth:`repro.telemetry.Telemetry.on_decode_chunk`);
 * speculative acceptance rate — ``spec_accepted / spec_drafted``.
 """
 from __future__ import annotations
@@ -36,6 +32,8 @@ import dataclasses
 import math
 from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence, \
     Tuple, Union
+
+from repro.core.policy import format_group_layout
 
 __all__ = ["Counter", "Gauge", "Histogram", "Metric", "MetricsRegistry",
            "TICK_BUCKETS", "SECONDS_BUCKETS", "format_group_layout",
@@ -251,12 +249,6 @@ class MetricsRegistry:
 # ------------------------------------------------------- EngineStats twins
 # EngineStats dict fields keyed by tier name -> labeled counter.
 _TIER_DICT_FIELDS = ("decode_steps_by_tier", "tokens_by_tier")
-
-
-def format_group_layout(layout: Tuple[Tuple[str, int], ...]) -> str:
-    """Stable label text of a mixed-tier group layout:
-    ``(("8/8", 2), ("4/4", 1))`` -> ``"8/8x2+4/4x1"``."""
-    return "+".join(f"{tier}x{rows}" for tier, rows in layout)
 
 
 def sync_engine_stats(registry: MetricsRegistry, stats: Any,

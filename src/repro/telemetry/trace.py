@@ -1,5 +1,5 @@
-"""Span tracing on the serving engine's dual clock, exported as Chrome
-trace-event JSON (loadable in Perfetto / ``chrome://tracing``).
+"""Request-lifecycle tracing on the serving engine's dual clock, exported
+as Chrome trace-event JSON (loadable in Perfetto / ``chrome://tracing``).
 
 The engine has TWO clocks and every span carries both:
 
@@ -14,9 +14,10 @@ The engine has TWO clocks and every span carries both:
 
 Track taxonomy (one Chrome *thread* per track, all in pid 1):
 
-* track 0, ``engine`` — one complete ("X") span per jitted dispatch:
-  ``prefill``, ``decode_chunk``, ``spec_round``; instant ("i") events for
-  ``migrate``, ``preempt``, ``resume``, ``shed``.
+* track 0, ``engine`` — instant ("i") events for ``migrate``,
+  ``preempt``, ``resume``, ``shed``.  The engine's phases and the device
+  time of its dispatches are not here: they are ``serve.*`` spans and
+  device ops of a ``jax.profiler`` trace (docs/observability.md).
 * track ``uid + 1``, ``req <uid>`` — the request lifecycle as contiguous
   phase spans ``queued`` / ``running`` / ``suspended`` (QUEUED -> RUNNING
   -> SUSPENDED/... transitions close one span and open the next), closed
@@ -89,17 +90,6 @@ class Tracer:
             "ts": self._us(self.now()), "s": "t",
             "args": dict(args or {}),
         })
-
-    def dispatch(self, name: str, start_s: float, *, ticks: float,
-                 ticks_end: float,
-                 args: Optional[Dict[str, Any]] = None) -> None:
-        """One engine-track dispatch span ending NOW, stamped with both
-        clocks (``ticks``/``ticks_end`` are scheduler-clock)."""
-        self._ensure_track(ENGINE_TRACK, "engine")
-        merged: Dict[str, Any] = {"ticks": ticks, "ticks_end": ticks_end}
-        merged.update(args or {})
-        self.complete(ENGINE_TRACK, name, start_s, self.now(),
-                      cat="dispatch", args=merged)
 
     def engine_instant(self, name: str, *, ticks: float,
                        args: Optional[Dict[str, Any]] = None) -> None:

@@ -52,8 +52,10 @@ def test_one_chip_phases_on_cpu(capsys):
 @pytest.fixture
 def cache_config():
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_compile_cache_follows_env(monkeypatch, cache_config):
@@ -61,6 +63,7 @@ def test_compile_cache_follows_env(monkeypatch, cache_config):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
     assert compile_cache.use_compile_cache() == "/elsewhere"
     assert jax.config.jax_compilation_cache_dir == before
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_compile_cache_defaults_to_repo(monkeypatch, cache_config):
@@ -68,3 +71,4 @@ def test_compile_cache_defaults_to_repo(monkeypatch, cache_config):
     want = os.path.join(REPO, ".jax_cache")
     assert compile_cache.use_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
+    assert jax.config.jax_compilation_cache_include_metadata_in_key

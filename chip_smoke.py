@@ -3,6 +3,7 @@
 
     python chip_smoke.py              # one chip: serve, reference, packed
     python chip_smoke.py --mesh4      # four chips: tensor-parallel serving
+    python chip_smoke.py --kv-identity  # one chip: mixed vs homogeneous KV
 
 The model is granite-3-8b (d_model 4096, 32/8 heads, head_dim 128, d_ff
 12800, vocab 49155) with its depth cut to ``LAYERS`` of 40 layers and
@@ -21,6 +22,13 @@ bf16, 8 and 4 in one mixed arena, so every decode batch is mixed-tier):
              for bit;
   packed     ``backend="pallas"`` on the packed store; its tokens must equal
              the serve phase's.
+
+``--kv-identity`` runs only the KV-arena check, on the packed store: the
+requests at ``KV_IDENTITY_NEW`` tokens each through the mixed KV arena,
+then through an engine whose whole arena is homogeneous at one tier's KV
+precision (bf16 for 8/8, int4 for 2/2); that tier's token streams must be
+identical.  Decode attention reads every encoding through one kernel on
+the chip, so this holds there as it does on the CPU's jnp path.
 
 ``--mesh4`` runs only the tensor-parallel path: the same model unsharded on
 one chip, then ``ServeEngine(mesh=make_serve_mesh(4))`` on the same
@@ -58,6 +66,8 @@ MAX_BATCH = 8
 MAX_LEN = 1024
 DECODE_CHUNK = 8
 PROMPT_BUCKET = 640                 # one prefill program per tier
+KV_IDENTITY_TIERS = ("8/8", "2/2")  # bf16 and int4 KV
+KV_IDENTITY_NEW = 129               # 387 tokens at 8/8, 258 at 2/2
 
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -102,9 +112,10 @@ class Smoke:
         self.model = LM(self.cfg)
         self.clock = CompileClock()
 
-    def schedule(self, backend: str) -> Any:
+    def schedule(self, backend: str, mixed_kv: bool = True) -> Any:
         from repro.core.policy import uniform_schedule
-        return uniform_schedule(TIERS, backend=backend, kv_tiers=KV_TIERS)
+        return uniform_schedule(TIERS, backend=backend,
+                                kv_tiers=KV_TIERS if mixed_kv else None)
 
     def init_params(self) -> Any:
         """Seeded float weights, made on the device."""
@@ -120,9 +131,10 @@ class Smoke:
                         tier=names[i % len(names)])
                 for i, n in enumerate(self.prompt_lens)]
 
-    def engine(self, params: Any, backend: str, **kw: Any) -> Any:
+    def engine(self, params: Any, backend: str, mixed_kv: bool = True,
+               **kw: Any) -> Any:
         from repro.launch.serve import build_engine
-        sched = self.schedule(backend)
+        sched = self.schedule(backend, mixed_kv)
         return build_engine(self.model, params, policy=sched.policy_for(),
                             schedule=sched, max_batch=self.max_batch,
                             max_len=self.max_len, decode_chunk=DECODE_CHUNK,
@@ -242,6 +254,26 @@ def run_one_chip(smoke: Smoke, expect_kernels: bool = True) -> None:
     check_equal("packed", smoke.serve("packed", packed), served)
 
 
+def run_kv_identity(smoke: Smoke) -> None:
+    params = smoke.init_params()
+    mixed = smoke.engine(params, "pallas", packed=True)
+    del params
+    served = smoke.serve("kv_mixed", mixed)
+    tiers = {r.uid: r.tier for r in smoke.requests()}
+    store = mixed.params
+    del mixed
+    gc.collect()
+    for tier in KV_IDENTITY_TIERS:
+        homog = smoke.engine(store, "pallas", mixed_kv=False, packed=True,
+                             kv_bits=KV_TIERS[tier])
+        got = smoke.serve(f"kv_{tier}", homog)
+        del homog
+        gc.collect()
+        keep = [u for u, t in tiers.items() if t == tier]
+        check_equal(f"kv {tier} (KV {KV_TIERS[tier] or 'bf16'})",
+                    {u: got[u] for u in keep}, {u: served[u] for u in keep})
+
+
 def run_mesh4(smoke: Smoke) -> None:
     from repro.launch.mesh import make_serve_mesh
     params = smoke.init_params()
@@ -265,6 +297,8 @@ def main(argv: Sequence[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--mesh4", action="store_true",
                     help="run only the 4-chip tensor-parallel path")
+    ap.add_argument("--kv-identity", action="store_true",
+                    help="run only the mixed-vs-homogeneous KV check")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     devices = jax.devices()
@@ -288,9 +322,12 @@ def main(argv: Sequence[str] | None = None) -> int:
           f"{cfg.head_dim}, d_ff {cfg.d_ff}, vocab {cfg.vocab_size}); "
           f"depth cut to {LAYERS} of 40 layers; seed {args.seed}")
     t0 = time.time()
-    smoke = Smoke(cfg, seed=args.seed)
+    smoke = Smoke(cfg, seed=args.seed,
+                  max_new=KV_IDENTITY_NEW if args.kv_identity else MAX_NEW)
     if args.mesh4:
         run_mesh4(smoke)
+    elif args.kv_identity:
+        run_kv_identity(smoke)
     else:
         run_one_chip(smoke)
     print(f"smoke wall, not a benchmark: total {time.time() - t0:.1f}s, "

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.core.policy import LayerPrecision, PrecisionPolicy, PrecisionSchedule
 from repro.distributed import tp_serve
 from repro.distributed.sharding import shard
-from repro.kernels import ops
+from repro.kernels import kv_attention, ops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,6 +388,16 @@ class KVCache:
         return self.kv_bits is not None
 
     @property
+    def tiers(self) -> tuple:
+        """The tier codes the stored lanes serve: ``modes`` for the mixed
+        arena, else the one tier of the homogeneous encoding."""
+        if self.mixed:
+            return self.modes
+        if self.quantized:
+            return (8,)
+        return (4,) if self.packed4 else (16,)
+
+    @property
     def head_dim(self) -> int:
         if self.mixed:
             lanes = self.k.shape[-1]
@@ -612,15 +622,40 @@ jax.tree_util.register_dataclass(
     meta_fields=["modes"])
 
 
+def decode_kernel_engages(max_len: int, heads: int, kv_heads: int,
+                          head_dim: int) -> bool:
+    """Whether :func:`decode_attention` runs the Pallas kernel
+    (``kernels.kv_attention``) for caches of these shapes — the head
+    counts a device holds, local ones inside a mesh engine's shard_map:
+    on the TPU, for every encoding, when the shapes tile and the scratch
+    fits VMEM; otherwise the jnp path."""
+    return ops._on_tpu() and kv_attention.tiles_on_tpu(max_len, heads,
+                                                       kv_heads, head_dim)
+
+
 def decode_attention(q, cache: KVCache):
     """Single-step attention against a cache. q: [B, 1, H, Dh].
 
-    Grouped (kvh, g) einsum form — no K/V repeat, operands stay in the cache
-    dtype (bf16/int8-dequant) with f32 accumulation via
-    preferred_element_type, so the big cache tensors are never materialized
-    in f32 and the head_dim contraction runs sharded (§Perf decode iters)."""
+    On the TPU (:func:`decode_kernel_engages`) one Pallas kernel reads the
+    cache as stored: only each slot's filled blocks, dequantized in VMEM at
+    the slot's own tier, so no arena-sized bf16 K/V ever exists.  Every
+    encoding goes through it there, which keeps a mixed-arena slot
+    bit-identical to the homogeneous cache at its tier on the chip.
+
+    Elsewhere: the grouped (kvh, g) einsum form over :meth:`KVCache.read` —
+    no K/V repeat, operands stay in the cache dtype (bf16/int8-dequant)
+    with f32 accumulation via preferred_element_type, so the big cache
+    tensors are never materialized in f32 and the head_dim contraction
+    runs sharded (§Perf decode iters)."""
     with jax.named_scope("attention"):
         b, sq, h, dh = q.shape
+        if sq == 1 and decode_kernel_engages(cache.k.shape[1], h,
+                                             cache.k.shape[2], dh):
+            out = kv_attention.kv_decode_attention(
+                q.reshape(b, h, dh), cache.k, cache.v, cache.k_scale,
+                cache.v_scale, cache.length, cache.kv_bits,
+                tiers=cache.tiers, interpret=ops._interpret())
+            return out.reshape(b, sq, h, dh)
         k, v = cache.read(q.dtype)
         sk = k.shape[1]
         kvh = k.shape[2]

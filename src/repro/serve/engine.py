@@ -87,8 +87,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import checkpoint as checkpoint_lib
 from repro.core.policy import PrecisionPolicy, format_group_layout
 from repro.distributed import sharding_rules, tp_serve
-from repro.kernels import ops
-from repro.models.layers import Runtime
+from repro.kernels import kv_attention, ops
+from repro.models.layers import Runtime, decode_kernel_engages
 from repro.models.transformer import LM
 from repro.serve import slots as slots_lib
 from repro.serve.handle import RequestHandle, RequestStatus, TokenEvent
@@ -271,7 +271,17 @@ class EngineStats:
     speculative round emitted (accepted drafts + correction/bonus
     tokens), so ``spec_accepted / spec_drafted`` is the acceptance rate
     and ``spec_verify_steps / spec_emitted`` the verify-tier steps per
-    emitted token (< 1 iff speculation beats plain decoding)."""
+    emitted token (< 1 iff speculation beats plain decoding).
+
+    KV read accounting (plain decode chunks; speculative rounds are not
+    counted): ``kv_positions_read`` sums, over decode steps and slots, the
+    KV positions decode attention fetches per layer — whole blocks up to
+    each slot's fill point when the decode kernel engages
+    (``layers.decode_kernel_engages``), the whole ``max_len`` otherwise —
+    and ``kv_positions_reserved`` adds ``max_batch * max_len`` per decode
+    step, so their ratio is the share of the arena decode reads (1.0 on
+    the jnp path).  Both are computed on the host from the mirrored fill
+    points (no device sync) and stay 0 for a model without attention."""
 
     prefills: int = 0
     prefill_tokens: int = 0        # real (unpadded) prompt tokens prefilled
@@ -297,6 +307,8 @@ class EngineStats:
     spec_emitted: int = 0          # tokens emitted by speculative rounds
     layout_cache_hits: int = 0     # group-layout derivations skipped (cache)
     layout_cache_misses: int = 0   # group-layout derivations performed
+    kv_positions_read: int = 0     # KV positions decode fetched per layer
+    kv_positions_reserved: int = 0  # max_batch * max_len per decode step
     decode_steps_by_tier: Dict[str, int] = dataclasses.field(
         default_factory=dict)
     tokens_by_tier: Dict[str, int] = dataclasses.field(default_factory=dict)
@@ -517,6 +529,13 @@ class ServeEngine(_DeferredErrors):
         self._tok: npt.NDArray[np.int32] = np.zeros((max_batch,), np.int32)
         self._remaining: npt.NDArray[np.int32] = np.zeros((max_batch,),
                                                           np.int32)
+        # KV fill point of every slot (a freed slot keeps its last one)
+        # and the decode kernel's engagement: the KV read accounting.
+        self._kv_len: npt.NDArray[np.int64] = np.zeros((max_batch,),
+                                                       np.int64)
+        self._has_kv = any(m == "attn" for m, _ in model.pattern)
+        self._kv_kernel = self._has_kv and decode_kernel_engages(
+            max_len, *self._local_heads(), model.cfg.head_dim)
         # Per-slot sampling state (repro.spec.sampling), mirrored on host
         # and passed traced into every decode dispatch: raw request PRNG
         # keys, draw counters, temperature, top-k.  Greedy slots keep
@@ -1258,6 +1277,8 @@ class ServeEngine(_DeferredErrors):
         assert state is not None
         state.tokens = list(sus.tokens)
         state.remaining = sus.remaining
+        # The cache holds the prompt and every emitted token but the last.
+        self._kv_len[slot] = len(req.prompt) + len(sus.tokens) - 1
         self._tok[slot] = sus.last_token
         self._remaining[slot] = sus.remaining
         self._load_sampling_state(slot, req, draws=sus.draws)
@@ -1454,6 +1475,7 @@ class ServeEngine(_DeferredErrors):
                 jnp.float32(self._temp[slot]),
                 jnp.int32(self._topk[slot]), tier=req.tier)
         self.arena.tiers[slot] = req.tier
+        self._kv_len[slot] = plen
         self.stats.prefills += 1
         self.stats.prefill_tokens += plen
         # The first token was draw event 0 (sampled rows only).
@@ -1655,6 +1677,7 @@ class ServeEngine(_DeferredErrors):
         self.stats.decode_steps += n_steps
         self.stats.decode_slot_steps += int(actives.sum())
         self.stats.decode_idle_slot_steps += int((~actives).sum())
+        self._account_kv_reads(actives)
         if self.schedule is not None:
             occupied_tiers = {self.arena.tiers[slot]
                               for slot, _ in occupied} if self.mixed_tiers \
@@ -1688,6 +1711,31 @@ class ServeEngine(_DeferredErrors):
             self._release_done()
         self._raise_deferred()
         return events
+
+    def _local_heads(self) -> Tuple[int, int]:
+        """Query and KV heads of attention on one device: divided over the
+        mesh's ``model`` axis as ``layers.attention_apply`` divides them
+        (KV heads only when they shard)."""
+        cfg, tp = self.model.cfg, self._tp
+        h, kvh = cfg.num_heads, cfg.num_kv_heads
+        if tp is None:
+            return h, kvh
+        return h // tp.n, kvh // tp.n if tp.kv_shards else kvh
+
+    def _account_kv_reads(self, actives: npt.NDArray[np.bool_]) -> None:
+        """KV read accounting of one plain decode chunk (``actives``
+        [n_steps, B]): each step appends one position to every active slot,
+        then decode attention reads every slot up to its fill point."""
+        lens = np.minimum(self._kv_len[None, :] + np.cumsum(actives, axis=0),
+                          self.max_len)
+        self._kv_len = lens[-1]
+        if not self._has_kv:
+            return
+        reserved = lens.size * self.max_len
+        self.stats.kv_positions_reserved += reserved
+        self.stats.kv_positions_read += int(
+            kv_attention.positions_fetched(lens).sum()) \
+            if self._kv_kernel else reserved
 
     def _spec_dispatch(self, occupied: List[Tuple[int, Any]],
                        events: List[TokenEvent]) -> List[TokenEvent]:
@@ -1744,6 +1792,10 @@ class ServeEngine(_DeferredErrors):
         self.stats.spec_accepted += int(
             np.minimum(m[spec_mask], e[spec_mask]).sum())
         self.stats.spec_emitted += int(e[spec_mask].sum())
+        # Fill points after the round: plain slots appended their active
+        # draft steps, spec slots keep their emitted window (the rollback
+        # rewound the rest).
+        self._kv_len += dact.sum(axis=0) + np.where(spec_mask, e, 0)
         # Slot-step accounting identity (decode_slot_steps +
         # decode_idle_slot_steps == decode_steps * max_batch): spec slots
         # are busy all k+1 steps, plain slots their active draft steps.

@@ -49,6 +49,18 @@ def test_one_chip_phases_on_cpu(capsys):
     assert "packed: token streams identical" in out
 
 
+def test_kv_identity_phase_on_cpu(capsys):
+    """A mixed-KV-arena engine and homogeneous-KV engines at 8/8 (bf16)
+    and 2/2 (int4) emit identical token streams at those tiers."""
+    smoke = chip_smoke.Smoke(reduced_config("granite-3-8b"),
+                             prompt_lens=(12, 3, 7, 9, 5, 11), max_new=9,
+                             max_len=32, prompt_bucket=16)
+    chip_smoke.run_kv_identity(smoke)
+    out = capsys.readouterr().out
+    assert "kv 8/8 (KV bf16): token streams identical (18 tokens)" in out
+    assert "kv 2/2 (KV 4): token streams identical (18 tokens)" in out
+
+
 @pytest.fixture
 def cache_config():
     before = jax.config.jax_compilation_cache_dir

@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import act_quant as aq
 from repro.kernels import bitserial_matmul as bsm
 from repro.kernels import grouped_matmul as gmm
+from repro.kernels import kv_attention as kva
 from repro.kernels import ops
 
 D_MODEL, D_FF = 4096, 12800     # granite-3-8b (configs/granite_3_8b.py)
@@ -27,7 +28,8 @@ PREFILL_ROWS = 512
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The described v5e:2x2 topology (four chips)."""
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -39,14 +41,20 @@ def one_chip():
     # persistent cache; keep the cache out of these compiles.
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compile(sharding, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text       # the kernel itself, compiled
+    return text
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
@@ -86,3 +94,65 @@ def test_act_quant_compiles_at_d_ff(one_chip):
     _compile(one_chip,
              lambda x: aq.act_quant(ops._pad_to(x, bm, 0), bm=bm),
              ((m, k), jnp.float32))
+
+
+# The decode attention kernel at granite-3-8b's cache: 16 slots, 8 KV heads
+# of 256 byte lanes, 32 query heads of head_dim 128.
+B_KV, KVH, LANES, H, DH = 16, 8, 256, 32, 128
+# The longest cache whose scratch fits kva.MAX_VMEM at these heads.
+S_MAX = max(s for s in range(kva.BLOCK, 65536, kva.BLOCK)
+            if kva.tiles_on_tpu(s, H, KVH, DH))
+
+
+def _kv_shapes(s, h=H, kvh=KVH):
+    return (((B_KV, h, DH), jnp.bfloat16),
+            ((B_KV, s, kvh, LANES), jnp.uint8),
+            ((B_KV, s, kvh, LANES), jnp.uint8),
+            ((B_KV, s, kvh, 1), jnp.bfloat16),
+            ((B_KV, s, kvh, 1), jnp.bfloat16),
+            ((B_KV,), jnp.int32), ((B_KV,), jnp.int32))
+
+
+@pytest.mark.parametrize("s", [2560, 8192, S_MAX],
+                         ids=["cell", "long", "longest"])
+def test_kv_decode_attention_compiles(one_chip, s):
+    """The benchmark cell's decode attention over the (16, 4) mixed arena
+    (and 8192 positions, past the default scoped VMEM, and the longest
+    cache the kernel admits).  The arena reaches the kernel as it is laid
+    out: no copy or transpose of it (or of its scale rows) feeds the
+    call."""
+    text = _compile(
+        one_chip,
+        lambda q, k, v, ks, vs, n, bits: kva.kv_decode_attention(
+            q, k, v, ks, vs, n, bits, tiers=(16, 4)),
+        *_kv_shapes(s))
+    moved = [line for line in text.splitlines()
+             if f"[{B_KV},{s}," in line and (" copy(" in line
+                                             or " transpose(" in line)]
+    assert not moved, moved
+
+
+def test_kv_decode_attention_compiles_in_shard_map(v5e):
+    """Four-way tensor-parallel serving (``chip_smoke.py --mesh4``): inside
+    ``shard_map`` over the described v5e:2x2 the kernel gets each chip's
+    heads, 8 query and 2 KV heads of the (16, 8, 4) mixed arena."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(v5e.devices), ("model",))
+    heads, kv = P(None, "model", None), P(None, None, "model", None)
+    specs = (heads, kv, kv, kv, kv, P(None), P(None))
+    seen = []
+
+    def local(q, k, v, ks, vs, n, bits):
+        seen.append((q.shape[1], k.shape[2]))
+        return kva.kv_decode_attention(q, k, v, ks, vs, n, bits,
+                                       tiers=(16, 8, 4))
+
+    fn = jax.shard_map(local, mesh=mesh, in_specs=specs, out_specs=heads,
+                       check_vma=False)        # as the engine's programs
+    args = [jax.ShapeDtypeStruct(shape, d,
+                                 sharding=NamedSharding(mesh, spec))
+            for (shape, d), spec in zip(_kv_shapes(2560), specs)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert seen == [(H // 4, KVH // 4)]

@@ -22,7 +22,8 @@ import os
 import sys
 import tempfile
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 import numpy as np
 
@@ -124,28 +125,107 @@ class CompileClock:
 
 
 # ------------------------------------------------------------ the model
-def arch_config(cfg: Dict[str, Any]) -> Any:
-    """The program's ``ArchConfig`` for a configuration file: its registry
-    entry with the file's depth, RoPE base and head tying (the program's own
-    options), every width checked against the file."""
+# A configuration file's key -> the ``ArchConfig`` field that holds it,
+# set from the file wherever it states the key.  Widths: as published.
+WIDTHS = {"hidden_size": "d_model", "num_attention_heads": "num_heads",
+          "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
+          "intermediate_size": "d_ff", "mamba_d_state": "ssm_state",
+          "mamba_d_head": "ssm_headdim", "mamba_d_conv": "ssm_conv",
+          "mamba_expand": "ssm_expand",
+          "num_experts_per_tok": "experts_per_token"}
+# Counts a file may cut to what one chip holds (``reduced``).
+COUNTS = {"num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
+          "num_local_experts": "num_experts"}
+# The program's own options.
+OPTIONS = {"rope_theta": "rope_theta", "qk_norm": "qk_norm",
+           "tie_word_embeddings": "tie_embeddings",
+           "mamba_chunk_size": "ssm_chunk"}
+FIELDS = {**WIDTHS, **COUNTS, **OPTIONS}
+
+
+def named_fields(cfg: Dict[str, Any]) -> Set[str]:
+    """Program fields the file names as cut or departed from: the keys of
+    ``reduced`` and of each object in ``departures`` (its ``run`` values,
+    and ``program``: field -> why), each also as the field it stands for."""
+    names = set(cfg.get("reduced", {}))
+    for part in cfg.get("departures", {}).values():
+        if isinstance(part, dict):
+            names |= set(part)
+    return names | {FIELDS[n] for n in names if n in FIELDS}
+
+
+def as_type(old: Any, value: Any) -> Any:
+    """``value`` in the type of the field's registry value (``10000`` as a
+    float RoPE base, ``true`` as a bool), as is where that is None."""
+    return value if old is None else type(old)(value)
+
+
+def program_config(cfg: Dict[str, Any]) -> Any:
+    """The program's ``ArchConfig`` as the file states it: its registry
+    entry with every field the file's keys give (``FIELDS``) set from them,
+    then the file's ``program`` object (field -> value) laid over it, for
+    structure no published key states (``moe_every``, ``attn_every``,
+    ``shared_expert``).  A ``program`` field may not change a width; one
+    that changes anything else has to be named in ``reduced`` or
+    ``departures`` (``named_fields``); a field the program does not have
+    fails in ``dataclasses.replace``.  Each count in ``reduced`` is checked
+    against its cut value, and a width or count under ``departures["run"]``
+    is refused (``weights.served``)."""
     from repro.configs import get_config
     base = get_config(cfg["repro_arch"])
-    widths = {"d_model": "hidden_size", "num_heads": "num_attention_heads",
-              "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
-              "d_ff": "intermediate_size", "vocab_size": "vocab_size"}
-    for ours, theirs in widths.items():
-        if getattr(base, ours) != cfg[theirs]:
-            raise ValueError(f"{cfg['repro_arch']}: {ours} is "
-                             f"{getattr(base, ours)}, the configuration "
-                             f"says {theirs}={cfg[theirs]}")
-    if bool(base.qk_norm) != bool(cfg.get("qk_norm")):
-        raise ValueError("qk_norm differs from the configuration")
-    arch = dataclasses.replace(
-        base, num_layers=cfg["num_hidden_layers"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]))
+    run = weights.served(cfg)
+    if run.get("position_embedding_type", "rope") != "rope":
+        raise ValueError("the program applies RoPE in every attention "
+                         "layer; the configuration says "
+                         f"{run['position_embedding_type']}")
+    stated = dataclasses.replace(base, **{
+        field: as_type(getattr(base, field), cfg[key])
+        for key, field in FIELDS.items() if key in cfg})
+    program = cfg.get("program", {})
+    named = named_fields(cfg)
+    missing = object()
+    for field, value in program.items():
+        if field in WIDTHS.values():
+            raise ValueError(f"program sets {field}, a width: the "
+                             "configuration states it as published")
+        if getattr(stated, field, missing) != value and field not in named:
+            raise ValueError(
+                f"program sets {field}={value!r} where {cfg['repro_arch']} "
+                f"has {getattr(stated, field, None)!r}: name it in reduced "
+                "or departures")
+    for key in cfg.get("reduced", {}):
+        published, held = weights.cut(cfg, key)
+        if cfg[key] != held:
+            raise ValueError(f"reduced says {key} {published} -> {held}, "
+                             f"the configuration holds {cfg[key]}")
+    arch = dataclasses.replace(stated, **program)
     if arch.padded_vocab != cfg["padded_vocab"]:
         raise ValueError("padded vocabulary differs from the configuration")
+    return arch
+
+
+def arch_config(cfg: Dict[str, Any]) -> Any:
+    """``program_config``, with every stacked leaf of the program's tree
+    checked against the shapes the file's layer list states
+    (``weights.stack_shapes``): held experts, the router over the published
+    count, a shared expert of its own width, Mamba-2's projections and the
+    order of layer kinds."""
+    import jax
+    from repro.models.transformer import LM
+    arch = program_config(cfg)
+    tree = jax.eval_shape(LM(arch).init, jax.random.PRNGKey(0))
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    stacked = {weights.path_str(p): tuple(s.shape) for p, s in flat
+               if weights.path_str(p).startswith(weights.STACKED_PREFIX)}
+    stated = weights.stack_shapes(cfg)
+    odd = sorted(p for p in set(stacked) | set(stated)
+                 if stacked.get(p) != stated.get(p))
+    if odd:
+        raise ValueError(
+            f"{cfg['repro_arch']}: the program's layers differ from the "
+            "configuration's at " + "; ".join(
+                f"{p}: program {stacked.get(p)}, configuration "
+                f"{stated.get(p)}" for p in odd))
     return arch
 
 
@@ -476,7 +556,12 @@ class Run:
     def check(self) -> Dict[str, Any]:
         """The widest gap by which a served token's logit lies below the
         reference's best at its position, over every sampled token of every
-        tier, against its limit."""
+        tier, against its limit.  A configuration that names no reference
+        yet (a sizing run) compares nothing, so its run is not correct."""
+        if "reference" not in self.cfg:
+            log("check: the configuration names no reference; nothing "
+                "compared")
+            return {}
         ref = load_module("references", self.cfg["reference"])
         picked = self.sample()
         if not picked:
